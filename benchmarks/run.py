@@ -12,8 +12,11 @@ import argparse
 import time
 import traceback
 
+from repro.runtime.chip import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--smoke", action="store_true",

@@ -18,9 +18,11 @@ from repro.distributed import EvalService, ShardedEvaluator
 from repro.perfmodel import EvalRequest, ModelEvaluator, get_evaluator
 from repro.perfmodel.designspace import SPACE
 from repro.perfmodel.sweep import SweepEngine
+from repro.runtime.chip import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workers", type=int, default=2)
     ap.add_argument("--mode", default="thread",

@@ -12,9 +12,11 @@ from repro.core.loop import LuminaDSE
 from repro.perfmodel import make_evaluator
 from repro.perfmodel.designspace import SPACE, A100_REFERENCE
 from repro.perfmodel.workload import from_arch
+from repro.runtime.chip import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="rwkv6-7b")
     ap.add_argument("--budget", type=int, default=150)

@@ -11,9 +11,11 @@ import argparse
 from repro.perfmodel import get_evaluator
 from repro.perfmodel.designspace import SPACE
 from repro.perfmodel.sweep import SweepEngine
+from repro.runtime.chip import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--stop", type=int, default=None,
                     help="sweep only flat ids [0, STOP) instead of the full space")

@@ -11,11 +11,13 @@ ONE scenario's stall classes.
 from repro.core.campaign import CampaignRunner
 from repro.perfmodel import get_evaluator
 from repro.perfmodel.sweep import SweepEngine
+from repro.runtime.chip import enable_compile_cache
 
 STOP = 150_000          # slice of the 4,741,632-design space (demo scale)
 
 
 def main() -> None:
+    enable_compile_cache()
     zoo = get_evaluator("proxy", suite="zoo")
     print(f"zoo suite: {len(zoo.scenarios)} scenarios, "
           f"{len(zoo.workloads)} stacked workloads")

@@ -6,9 +6,11 @@ reference and print the Pareto-optimal designs it finds.
 from repro.perfmodel import get_evaluator
 from repro.perfmodel.designspace import SPACE
 from repro.core.loop import LuminaDSE
+from repro.runtime.chip import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     # the paper's evaluation workload: one GPT-3 175B layer, TP=8,
     # batch 8, seq 2048 (TTFT) / 1024th output token (TPOT), FP16.
     # The high-fidelity target tier pays the budget; the roofline proxy

@@ -13,9 +13,11 @@ import numpy as np
 from repro.core.campaign import CampaignRunner
 from repro.perfmodel import ModelEvaluator, OracleEvaluator, get_evaluator
 from repro.perfmodel.designspace import SPACE
+from repro.runtime.chip import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--budget", type=int, default=20)
     ap.add_argument("--seeds-per-campaign", type=int, default=1)

@@ -28,6 +28,7 @@ from repro.distributed import (EvalService, FaultEvent, FaultPlan,
                                ShardedEvaluator)
 from repro.perfmodel import EvalRequest, ModelEvaluator, get_evaluator
 from repro.perfmodel.designspace import SPACE
+from repro.runtime.chip import enable_compile_cache
 from repro.serve import (Gateway, Keyring, MembershipView, Registrar,
                          WorkerOptions, start_worker_process)
 
@@ -35,6 +36,7 @@ KEYS = {"fleet": b"demo-cluster-secret"}
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--budget", type=int, default=10)
     args = ap.parse_args()

@@ -7,9 +7,11 @@ same serve API).
 import argparse
 
 from repro.launch.serve import serve
+from repro.runtime.chip import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--batch", type=int, default=4)

@@ -23,10 +23,12 @@ from repro.obs import (Tracer, completeness_errors, render_tree,
                        trace_events, validate_trace_events, write_trace)
 from repro.perfmodel import EvalRequest, ModelEvaluator, get_evaluator
 from repro.perfmodel.designspace import SPACE
+from repro.runtime.chip import enable_compile_cache
 from repro.serve import Gateway, start_worker_process
 
 
 def main() -> None:
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     w1 = start_worker_process()
     w2 = start_worker_process()

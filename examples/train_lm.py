@@ -16,9 +16,11 @@ import numpy as np
 from repro.configs import get_arch
 from repro.launch.train import train
 import repro.launch.train as T
+from repro.runtime.chip import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--ci", action="store_true")

@@ -7,10 +7,12 @@ vectorized JAX model brings it to seconds, and this kernel is the TPU-native
 tiling of that evaluation for full-space (4.7M-point) sweeps.
 
 Tiling: grid = (n_design_blocks,); each step loads a (block_b, 8) tile of
-decoded design values into VMEM plus the whole (n_ops, 8) operator table
-(tiny — every workload here is < 128 ops), and runs a fori_loop over ops
-accumulating latency and the four per-stall-class times entirely in
-registers/VMEM.  Output tile: (block_b, 8) = [latency, 4 stalls, area, 0, 0].
+decoded design values into VMEM, keeps the whole (n_ops, 8) operator table
+(tiny — every workload here is < 128 ops) in SMEM, and runs a fori_loop
+over ops that reads each op's fields as scalars straight from the SMEM ref
+(Mosaic cannot lower a dynamic slice of a loaded vector value), accumulating
+latency and the four per-stall-class times in registers/VMEM.  Output tile:
+(block_b, 8) = [latency, 4 stalls, area, 0, 0].
 
 Math mirrors repro.perfmodel.roofline exactly (ref.py delegates to it).
 """
@@ -21,6 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.perfmodel.hardware import (
     AREA_BASE, AREA_CORE_BASE, AREA_PER_CHANNEL, AREA_PER_GBUF_MB,
@@ -41,7 +44,6 @@ def _ceil_div(a, b):
 
 def _ppa_kernel(dv_ref, ops_ref, out_ref, *, n_ops: int, tp: float):
     dv = dv_ref[...].astype(jnp.float32)          # (bb, 8)
-    ops = ops_ref[...].astype(jnp.float32)        # (n_ops, 8)
 
     cores, sub, sa, vw = dv[:, CORES], dv[:, SUBLANES], dv[:, SA], dv[:, VW]
     sram, gbuf_mb, chan, links = dv[:, SRAM], dv[:, GBUF], dv[:, CHAN], dv[:, LINKS]
@@ -58,10 +60,10 @@ def _ppa_kernel(dv_ref, ops_ref, out_ref, *, n_ops: int, tp: float):
 
     def body(i, carry):
         lat, stalls = carry
-        kind = ops[i, OP_KIND]
-        flops, nbytes = ops[i, OP_FLOPS], ops[i, OP_BYTES]
-        m, n, k = ops[i, OP_M], ops[i, OP_N], ops[i, OP_K]
-        comm, count = ops[i, OP_COMM], ops[i, OP_COUNT]
+        kind = ops_ref[i, OP_KIND]                # SMEM scalar reads
+        flops, nbytes = ops_ref[i, OP_FLOPS], ops_ref[i, OP_BYTES]
+        m, n, k = ops_ref[i, OP_M], ops_ref[i, OP_N], ops_ref[i, OP_K]
+        comm, count = ops_ref[i, OP_COMM], ops_ref[i, OP_COUNT]
 
         # matmul utilization (mirrors roofline.matmul_utilization)
         u_k = k / (_ceil_div(k, sa) * sa)
@@ -128,9 +130,9 @@ def ppa_eval_fwd(design_values: jnp.ndarray, op_table: jnp.ndarray, *,
         grid=(b // block_b,),
         in_specs=[
             pl.BlockSpec((block_b, 8), lambda i: (i, 0)),
-            pl.BlockSpec((n_ops, 8), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),    # whole op table
         ],
         out_specs=pl.BlockSpec((block_b, 8), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 8), jnp.float32),
         interpret=interpret,
-    )(design_values, op_table)
+    )(design_values, op_table.astype(jnp.float32))

@@ -39,6 +39,7 @@ from repro.launch import steps as ST
 from repro.launch.mesh import make_production_mesh, data_axes, activate_mesh
 from repro.models import build_model
 from repro.optim import AdamWConfig
+from repro.runtime.chip import enable_compile_cache
 
 # ---- hardware constants (assignment: TPU v5e-like target) ----
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
@@ -187,6 +188,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

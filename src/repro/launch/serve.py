@@ -16,6 +16,7 @@ from repro.configs import get_arch
 from repro.launch.mesh import activate_mesh
 from repro.launch.train import choose_mesh
 from repro.models import build_model
+from repro.runtime.chip import enable_compile_cache
 
 
 def serve(arch: str, batch: int, prompt_len: int, gen: int, smoke: bool,
@@ -64,6 +65,7 @@ def serve(arch: str, batch: int, prompt_len: int, gen: int, smoke: bool,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--batch", type=int, default=4)

@@ -27,6 +27,7 @@ from repro.launch.mesh import make_mesh, data_axes, activate_mesh
 from repro.optim import AdamWConfig, adamw_init
 from repro.models import build_model
 from repro.runtime import StragglerMonitor
+from repro.runtime.chip import enable_compile_cache
 
 
 def choose_mesh():
@@ -124,6 +125,7 @@ def train(arch: str, steps: int, batch: int, seq: int, smoke: bool,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=200)

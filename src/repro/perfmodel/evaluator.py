@@ -316,7 +316,10 @@ def resolve_backend(backend: Optional[str],
 
 def _benchmark_backends(models: Mapping[str, RooflineModel],
                         probe: int = 1024) -> str:
-    """Time each kernel-capable candidate's fused objectives dispatch."""
+    """Time each kernel-capable candidate's fused objectives dispatch.
+
+    A candidate that fails to build or run raises: skipping it would hide
+    a broken backend behind a silently different choice."""
     best_name, best_t = "roofline", np.inf
     rng = np.random.default_rng(0)
     space = next(iter(models.values())).space
@@ -324,14 +327,11 @@ def _benchmark_backends(models: Mapping[str, RooflineModel],
     for name, spec in _BACKENDS.items():
         if spec.model_cls is not type(next(iter(models.values()))) and not spec.kernel:
             continue
-        try:
-            ev = ModelEvaluator(models, backend=name)
-            ev.objectives(idx)                      # compile + warm
-            t0 = time.perf_counter()
-            ev.objectives(idx)
-            dt = time.perf_counter() - t0
-        except Exception:
-            continue
+        ev = ModelEvaluator(models, backend=name)
+        ev.objectives(idx)                          # compile + warm
+        t0 = time.perf_counter()
+        ev.objectives(idx)
+        dt = time.perf_counter() - t0
         if dt < best_t:
             best_name, best_t = name, dt
     return best_name

@@ -10,9 +10,10 @@ metrics it physically influences:
   bound 2*M*N*K/sqrt(gbuf) (global-buffer reuse);
 * collectives            <- ring all-reduce / all-to-all on the ICI links.
 
-Evaluating the *entire* 4.7M-point space takes ~1 s on one device (the paper
-reports 6000 CPU-hours per 1000 LLMCompass samples — this is the substrate
-speedup that lets us run 1000-sample DSE campaigns in CI).
+Evaluating the *entire* 4.7M-point space takes a few seconds in the CPU
+container (a CPU-container time, not a chip measurement; the paper reports
+6000 CPU-hours per 1000 LLMCompass samples — this is the substrate speedup
+that lets us run 1000-sample DSE campaigns in CI).
 
 This module is the core of the surface :mod:`repro.analysis.influence`
 parses: ``RooflineModel._op_terms`` defines the derived -> op-term edges,

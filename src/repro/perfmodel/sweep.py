@@ -401,7 +401,11 @@ class SweepEngine:
         multiple = 1
         if shard and ndev > 1:
             from jax.sharding import NamedSharding, PartitionSpec as P
-            mesh = jax.make_mesh((ndev,), ("sweep",))
+            # Auto axes: the chunk step is written without sharding
+            # annotations, so the compiler partitions it (jax's default
+            # Explicit axes reject its gathers outside a mesh context)
+            mesh = jax.make_mesh((ndev,), ("sweep",),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
             self._sharding = NamedSharding(mesh, P("sweep"))
             multiple = ndev
         if backend == "pallas":
@@ -600,7 +604,10 @@ class SweepEngine:
         uops = {kk: jnp.asarray(vv) for kk, vv in stack.unique.items()}
         uops["count"] = jnp.ones(stack.n_unique)
         t = self._rep_model._op_terms(hwb, ops=uops)
-        lat = t["t_unit"] @ jnp.asarray(self._cmat_all).T   # (c, 2S)
+        # HIGHEST: a DEFAULT-precision f32 dot may run as one bf16 pass on
+        # the TPU (~1e-3 relative error in every scenario latency)
+        lat = jnp.matmul(t["t_unit"], jnp.asarray(self._cmat_all).T,
+                         precision=jax.lax.Precision.HIGHEST)  # (c, 2S)
         area = hw["area_mm2"]
         S = len(self.scenarios)
         ys = jnp.stack([lat[:, 0::2], lat[:, 1::2],
@@ -620,7 +627,8 @@ class SweepEngine:
             dom_g = _dominant_class(t2)                     # (c, P)
             cp = jnp.asarray(self._cmat_prefill).T          # (P, S)
             stall = jnp.stack(
-                [jnp.where(dom_g == k, t2["t_unit"], 0.0) @ cp
+                [jnp.matmul(jnp.where(dom_g == k, t2["t_unit"], 0.0), cp,
+                            precision=jax.lax.Precision.HIGHEST)
                  for k in range(_N_STALL)], axis=2)         # (c, S, 4)
             dom = jnp.argmax(stall, axis=2).astype(jnp.int32)
         return ys, dom

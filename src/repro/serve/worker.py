@@ -60,6 +60,7 @@ from typing import Dict, Optional, Tuple
 
 from repro.obs.metrics import Clock, MetricsRegistry
 from repro.obs.trace import Tracer
+from repro.runtime.chip import enable_compile_cache, refuse_spawn_on_tpu
 from repro.serve import codec as _codec
 from repro.serve import wire
 
@@ -306,25 +307,26 @@ class WorkerServer:
             answered = threading.Lock()
             done = [False]
 
-            def answer(msg_out: object) -> bool:
+            def answer(msg_out: object, count=None) -> None:
                 with answered:
                     if done[0]:
-                        return False
+                        return
                     done[0] = True
+                if count is not None:
+                    count()     # before the reply: whoever sees it, sees this
                 try:
                     reply(msg_out)
                 except (OSError, wire.WireError):
                     pass                    # client already gone
-                return True
 
             timer: Optional[threading.Timer] = None
             if self.options.deadline_s > 0:
                 def expire() -> None:
-                    if answer(wire.ErrorMsg(
-                            msg.seq, f"dispatch exceeded the "
-                            f"{self.options.deadline_s:g}s deadline",
-                            (), "quota.deadline")):
-                        self._c_quota_rejected.inc(kind="deadline")
+                    answer(wire.ErrorMsg(
+                        msg.seq, f"dispatch exceeded the "
+                        f"{self.options.deadline_s:g}s deadline",
+                        (), "quota.deadline"),
+                        lambda: self._c_quota_rejected.inc(kind="deadline"))
                 timer = threading.Timer(self.options.deadline_s, expire)
                 timer.daemon = True
                 timer.start()
@@ -340,8 +342,8 @@ class WorkerServer:
                 else:
                     rep = _eval_payload(evaluator, msg.payload)
                 self._h_eval.observe(self._clock() - t0)
-                if answer(wire.ResultMsg(msg.seq, rep, shipped_spans())):
-                    self._c_dispatches.inc()
+                answer(wire.ResultMsg(msg.seq, rep, shipped_spans()),
+                       self._c_dispatches.inc)
             except Exception as exc:        # noqa: BLE001 — wire boundary
                 answer(wire.ErrorMsg(msg.seq, f"{type(exc).__name__}: "
                                               f"{exc}", shipped_spans()))
@@ -545,7 +547,10 @@ def start_worker_process(host: str = "127.0.0.1", port: int = 0, *,
                          timeout_s: float = 120.0) -> WorkerHandle:
     """Spawn a worker daemon in a child process; returns once it is
     listening (the bound port travels back over a pipe, so ``port=0``
-    works).  ``options`` configures auth/quotas/membership in the child."""
+    works).  ``options`` configures auth/quotas/membership in the child.
+    Refuses on a TPU host: the child would need the chip this process
+    holds."""
+    refuse_spawn_on_tpu("start_worker_process")
     import multiprocessing as mp
     ctx = mp.get_context("spawn")
     parent, child = ctx.Pipe()
@@ -579,6 +584,7 @@ def _parse_addr(text: str) -> Tuple[str, int]:
 
 
 def main(argv: Optional[list] = None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.serve.worker",
         description="repro.serve evaluation worker daemon")

@@ -1,10 +1,7 @@
 """Design-space encode/decode round-trip properties (hypothesis)."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:            # offline container: deterministic fallback
-    from _hyp_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.perfmodel.designspace import SPACE, A100_REFERENCE, DESIGN_A
 

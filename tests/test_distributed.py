@@ -95,6 +95,15 @@ def test_sharded_process_mode_bit_identical():
         sharded.close()
 
 
+def test_process_mode_refuses_when_parent_holds_tpu(monkeypatch):
+    """A chip belongs to one process: on a TPU host the process pool
+    refuses instead of spawning children that cannot reach the chip."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="one process"):
+        ShardedEvaluator(_fresh(), workers=2, mode="process")
+
+
 class _FlakyPool:
     """Fails the first `fail_first` shard submissions, then delegates."""
     mode = "thread"

@@ -142,6 +142,20 @@ def test_auto_backend_resolves_to_registered_name():
     assert resolve_backend("auto", ct) == "roofline"
 
 
+def test_auto_backend_raises_when_a_candidate_fails(monkeypatch):
+    """backend="auto" never hides a broken candidate behind another pick."""
+    import repro.perfmodel.evaluator as E
+    monkeypatch.setattr(E, "_AUTO_CACHE", {})
+    monkeypatch.setattr(E, "_JIT_CACHE", {})
+
+    def broken(self, names):
+        raise NotImplementedError("kernel failed to lower")
+
+    monkeypatch.setattr(E.ModelEvaluator, "_build_kernel_objectives", broken)
+    with pytest.raises(NotImplementedError, match="failed to lower"):
+        resolve_backend("auto", get_evaluator("proxy").models)
+
+
 # ------------------------------------------------------- dispatch counting
 def test_one_fused_dispatch_per_dse_step():
     """Acceptance criterion: each budgeted DSE step issues exactly ONE fused

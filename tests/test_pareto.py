@@ -1,10 +1,7 @@
 """Property tests for Pareto/PHV machinery (hypothesis)."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:            # offline container: deterministic fallback
-    from _hyp_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.pareto import (pareto_mask, pareto_front, hypervolume,
                                hypervolume_mc, dominates_ref,

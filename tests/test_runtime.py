@@ -6,10 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:            # offline container: deterministic fallback
-    from _hyp_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.optim import (AdamWConfig, adamw_init, adamw_update, cosine_lr,
                          compress_grads, decompress_grads, ef_init)

@@ -211,6 +211,18 @@ def test_socket_pool_reconnect_reregisters(servers):
     pool.close()
 
 
+def test_start_worker_process_refuses_when_parent_holds_tpu(monkeypatch):
+    """No worker daemon is spawned on a TPU host: the child would need the
+    chip this process holds."""
+    import jax
+    import multiprocessing as mp
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = len(mp.active_children())
+    with pytest.raises(RuntimeError, match="one process"):
+        start_worker_process()
+    assert len(mp.active_children()) == before
+
+
 def test_socket_worker_sigkill_mid_stream_bit_identical():
     """Acceptance: SIGKILL a worker process while a stream of requests is
     in flight — the dead slot is evicted (elastic resize included) and

@@ -182,6 +182,38 @@ def test_sweep_checkpoint_rejects_mismatched_config(engine, tmp_path):
         shifted.run(0, 40_000, resume_from=ck)
 
 
+_SHARDED_CHECK = """
+import numpy as np, jax
+from repro.perfmodel import get_evaluator
+from repro.perfmodel.sweep import SweepEngine
+assert len(jax.devices()) == 4
+ev = get_evaluator("proxy")
+one = SweepEngine(ev, stall_topk=4, chunk_size=8192).run(0, 40_000)
+eng = SweepEngine(ev, stall_topk=4, chunk_size=8192, shard=True)
+res = eng.run(0, 40_000)
+assert len(eng._iota.devices()) == 4
+assert res.n_superior == one.n_superior
+assert np.array_equal(res.pareto_ids, one.pareto_ids)
+assert np.array_equal(res.topk_ids, one.topk_ids)
+assert np.array_equal(res.stall_topk_ids, one.stall_topk_ids)
+"""
+
+
+def test_sharded_sweep_identical_on_four_devices():
+    """shard=True spreads each chunk over every local device and reproduces
+    the one-device sweep exactly.  Four virtual CPU devices need a fresh
+    process: the device count is fixed when JAX starts."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(repo, "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", _SHARDED_CHECK], env=env,
+                       capture_output=True, text=True, timeout=600, cwd=repo)
+    assert r.returncode == 0, r.stderr[-3000:]
+
+
 def test_pallas_backend_rejects_compass_models():
     with pytest.raises(ValueError, match="pallas"):
         SweepEngine(get_evaluator("target"), backend="pallas")
