@@ -5,7 +5,8 @@ Two claims, both ASSERTED (not just reported):
 * **always-on-cheap** — the full dispatch tick path costs < 3% extra
   with a real :class:`~repro.obs.Tracer` attached vs the default
   :data:`~repro.obs.NOOP` tracer (``obs,traced_overhead_pct``), and the
-  no-op span itself is sub-microsecond (``obs,noop_span_ns``);
+  no-op span itself is sub-microsecond while no profiler records
+  (``obs,noop_span_ns``);
 * **one causal tree across machines** — a ``Gateway.evaluate`` against
   two SPAWNED worker processes, with a chaos crash injected on the
   first dispatch and one worker SIGKILLed between requests, still

@@ -314,7 +314,7 @@ class CampaignRunner:
                              space=space, ref_point=ref_point,
                              area_budget=area_budget, seed=seed,
                              engine=self.ee, workloads=workloads,
-                             primary_map=primary_map)
+                             primary_map=primary_map, tracer=self.tracer)
         self.ref_point = self.dse.ref_point
 
     @property

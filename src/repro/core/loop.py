@@ -37,6 +37,7 @@ from repro.core.quale import derive_influence_map, InfluenceMap
 from repro.core.quane import sensitivity_analysis
 from repro.core.refine import RefinementLoop
 from repro.core.strategy import Directive, StrategyEngine
+from repro.obs.trace import NOOP
 from repro.perfmodel.designspace import DesignSpace, SPACE, A100_REFERENCE
 from repro.perfmodel.evaluator import Evaluator, as_evaluator, pair_view
 
@@ -95,36 +96,39 @@ class Campaign:
 
     def propose(self) -> Tuple[np.ndarray, Optional[Directive]]:
         """Next candidate design (and the directive that produced it)."""
-        if self._pending_inits:
-            self._directive = None
-            idx = self._pending_inits.pop(0)
-            # claim the seed NOW so sibling campaigns proposing later in the
-            # same round never spend budget re-evaluating it
-            self.visited.add(tuple(idx))
-            return idx, None
-        self.step += 1
-        focus = FOCUS_CYCLE[(self.step - 1) % len(FOCUS_CYCLE)]
-        base = self.tm.best(weights=_focus_weights(focus)) or self.tm.samples[-1]
-        rep_t, rep_p = self.dse.ee.reports(base.idx)  # cached reads, cheap
-        report = rep_p if focus == "tpot" else rep_t
-        directive = self.se.propose(base.idx, report, self.sens, self.tm,
-                                    focus, area_budget=self.dse.area_budget,
-                                    visited=self.visited)
-        self.visited.add(tuple(directive.new_idx))
-        self._directive = directive
-        return directive.new_idx, directive
+        with self.dse.tracer.span("dse.propose"):
+            if self._pending_inits:
+                self._directive = None
+                idx = self._pending_inits.pop(0)
+                # claim the seed NOW so sibling campaigns proposing later
+                # in the same round never spend budget re-evaluating it
+                self.visited.add(tuple(idx))
+                return idx, None
+            self.step += 1
+            focus = FOCUS_CYCLE[(self.step - 1) % len(FOCUS_CYCLE)]
+            base = (self.tm.best(weights=_focus_weights(focus))
+                    or self.tm.samples[-1])
+            rep_t, rep_p = self.dse.ee.reports(base.idx)  # cached, cheap
+            report = rep_p if focus == "tpot" else rep_t
+            directive = self.se.propose(
+                base.idx, report, self.sens, self.tm, focus,
+                area_budget=self.dse.area_budget, visited=self.visited)
+            self.visited.add(tuple(directive.new_idx))
+            self._directive = directive
+            return directive.new_idx, directive
 
     def observe(self, sample: Sample) -> None:
         """Record one evaluated proposal and run the refinement pass."""
-        self.tm.add(sample)
-        self.visited.add(tuple(sample.idx))
-        if self._directive is not None:
-            note = self.dse.refiner.update(self.sens, self.tm, sample)
-            if note:
-                self.notes.append(f"step {self.step}: {note}")
-            self.sens = self.dse.refiner.maybe_reanchor(
-                self.sens, self.tm, self.dse.proxy, self.step)
-        self._directive = None
+        with self.dse.tracer.span("dse.observe"):
+            self.tm.add(sample)
+            self.visited.add(tuple(sample.idx))
+            if self._directive is not None:
+                note = self.dse.refiner.update(self.sens, self.tm, sample)
+                if note:
+                    self.notes.append(f"step {self.step}: {note}")
+                self.sens = self.dse.refiner.maybe_reanchor(
+                    self.sens, self.tm, self.dse.proxy, self.step)
+            self._directive = None
 
     def result(self) -> DSEResult:
         return DSEResult(
@@ -148,15 +152,20 @@ class LuminaDSE:
                  engine: Optional[ExplorationEngine] = None,
                  imap: Optional[InfluenceMap] = None,
                  workloads: Optional[Tuple[str, str]] = None,
-                 primary_map: Optional[dict] = None):
+                 primary_map: Optional[dict] = None,
+                 tracer=None):
         """``engine`` lets parallel campaigns share ONE ExplorationEngine
         (one budget counter, one report cache); ``imap`` injects an already
         derived influence map so K campaigns pay acquisition once;
         ``workloads`` picks the (prefill, decode) pair of a multi-workload
         evaluator this loop optimizes (e.g. one zoo-suite scenario);
         ``primary_map`` overrides the source-extracted AHK primary edges
-        (stall -> parameter) for every campaign's SE — the ablation hook."""
+        (stall -> parameter) for every campaign's SE — the ablation hook;
+        ``tracer`` (default the no-op tracer) spans each step of
+        :meth:`run` as ``dse.step`` and each campaign's ``propose`` and
+        ``observe`` as ``dse.propose`` and ``dse.observe``."""
         self.space = space
+        self.tracer = tracer if tracer is not None else NOOP
         evaluator = as_evaluator(evaluator)
         self.ee = (engine if engine is not None
                    else ExplorationEngine(evaluator, workloads=workloads))
@@ -223,10 +232,11 @@ class LuminaDSE:
         campaign = self.start(init)
         budget_stop = self.ee.evals + budget
         while self.ee.evals < budget_stop:
-            idx, directive = campaign.propose()
-            sample = self.ee.evaluate(idx, step=campaign.step,
-                                      directive=directive)
-            campaign.observe(sample)
+            with self.tracer.span("dse.step"):
+                idx, directive = campaign.propose()
+                sample = self.ee.evaluate(idx, step=campaign.step,
+                                          directive=directive)
+                campaign.observe(sample)
             if step_callback is not None:
                 step_callback(campaign, sample)
         return campaign.result()

@@ -17,6 +17,17 @@ Model (deliberately small — this rides the service tick hot path):
 ``NOOP`` (a :class:`NoopTracer`) is the default everywhere; every
 method is a constant-time no-op so instrumentation left in place costs
 effectively nothing when tracing is off.
+
+Every ``span()`` block, on a :class:`Tracer` or on ``NOOP``, is also a
+``jax.profiler.TraceAnnotation`` of the span's name, with the span's
+attributes as its arguments (integers stay integers).  So a program's
+spans land on the host timeline of any ``jax.profiler`` trace, on the
+device trace's clock, whether or not a recording tracer is attached.
+While no profiler trace records, a span opens no annotation and pays one
+check of the profiler's state (about 0.1 us).  Spans opened with
+``start()`` (detached or not) are not annotated: a profiler annotation has
+to end on the thread that opened it, and ``start``/``finish`` do not
+promise that.
 """
 
 from __future__ import annotations
@@ -35,6 +46,21 @@ SPAN_STATUSES = ("ok", "error", "lost")
 TraceContext = Tuple[str, str]
 
 DEFAULT_MAX_SPANS = 65536
+
+_ANNOTATION = None
+
+
+def _annotation(name: str, attrs: Dict[str, object]):
+    """A ``jax.profiler.TraceAnnotation`` for a ``span()`` block, or None
+    while no profiler trace is recording (an annotation opened then
+    records nothing).  JAX's profiler is imported on the first span."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    if not _ANNOTATION.is_enabled():
+        return None
+    return _ANNOTATION(name, **attrs)
 
 
 @dataclass
@@ -91,18 +117,24 @@ class Span:
 
 
 class _SpanHandle:
-    """Context manager returned by ``Tracer.span(...)``."""
+    """Context manager returned by ``Tracer.span(...)``: the span, and the
+    profiler annotation that puts it on the device trace's clock."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_ann")
 
     def __init__(self, tracer: "Tracer", span: Span) -> None:
         self._tracer = tracer
         self._span = span
+        self._ann = _annotation(span.name, span.attrs)
 
     def __enter__(self) -> Span:
+        if self._ann is not None:
+            self._ann.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         self._tracer.finish(self._span, status="error" if exc_type is not None else None)
         if exc_type is not None:
             self._span.attrs.setdefault("error", f"{exc_type.__name__}: {exc}")
@@ -269,12 +301,22 @@ class Tracer:
 
 
 class _NoopSpanHandle:
-    __slots__ = ()
+    """``NoopTracer.span(...)``: records nothing, but opens the span's
+    profiler annotation ``ann`` while a profiler trace is recording."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann=None) -> None:
+        self._ann = ann
 
     def __enter__(self) -> "_NoopSpan":
+        if self._ann is not None:
+            self._ann.__enter__()
         return _NOOP_SPAN
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -324,8 +366,10 @@ class NoopTracer:
     def lose(self, span: object, reason: str = "") -> None:
         pass
 
-    def span(self, name: str, **kw: object) -> _NoopSpanHandle:
-        return _NOOP_HANDLE
+    def span(self, name: str, *, parent=None,
+             **attrs: object) -> _NoopSpanHandle:
+        ann = _annotation(name, attrs)
+        return _NOOP_HANDLE if ann is None else _NoopSpanHandle(ann)
 
     def activate(self, span: object) -> _NoopSpanHandle:
         return _NOOP_HANDLE

@@ -47,11 +47,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import NOOP
 from repro.perfmodel.critical_path import StallReport, build_report
 from repro.perfmodel.designspace import DesignSpace, SPACE
 from repro.perfmodel.hardware import derive_hardware
 from repro.perfmodel.roofline import (RooflineModel, _JIT_CACHE,
-                                      _bucketed_call, _space_key,
+                                      _batch_bucket, _bucketed_call,
+                                      _space_key,
                                       _workload_fingerprint,
                                       stacked_workload_batches)
 from repro.perfmodel.workload import Scenario, WorkloadStack
@@ -348,13 +350,15 @@ class ModelEvaluator:
     derives the hardware spec once, and computes every workload's op terms —
     a single device dispatch per :meth:`evaluate` call regardless of the
     number of workloads or the detail level.  ``dispatches`` counts them
-    (the DSE loop asserts one per step).
+    (the DSE loop asserts one per step).  ``tracer`` (default the no-op
+    tracer) spans each call as ``eval.call`` (``rows``, ``bucket``) over
+    ``eval.upload``, ``eval.launch`` and ``eval.fetch``.
     """
 
     def __init__(self, models: Mapping[str, RooflineModel], *,
                  tier: str = "proxy", backend: Optional[str] = None,
                  scenarios: Optional[Tuple[Scenario, ...]] = None,
-                 stacked: Optional[bool] = None):
+                 stacked: Optional[bool] = None, tracer=None):
         if not models:
             raise ValueError("need at least one workload model")
         self.models: Dict[str, RooflineModel] = dict(models)
@@ -378,6 +382,7 @@ class ModelEvaluator:
                 "and compass-knob set (their op terms fuse into one pass)")
         self.stacked = eligible if stacked is None else bool(stacked)
         self.dispatches = 0            # fused jitted dispatch count
+        self.tracer = tracer if tracer is not None else NOOP
         self._fns: Dict[tuple, Callable] = {}
         self._stacks: Dict[Tuple[str, ...], WorkloadStack] = {}
 
@@ -482,22 +487,26 @@ class ModelEvaluator:
         if unknown:
             raise KeyError(f"unknown workloads {sorted(unknown)}; "
                            f"have {self.workloads}")
-        fn = self._fused_fn(request.detail, names)
-        out = _bucketed_call(fn, request.idx)        # ONE fused dispatch
-        self.dispatches += 1
-        per = out["per_workload"]
-        detail = request.detail
-        rep = PPAReport(
-            workloads=names, detail=detail, area=out["area"],
-            latency={nm: per[nm]["latency"] for nm in names})
-        if detail in ("ppa", "stalls"):
-            rep.op_time = {nm: per[nm]["op_time"] for nm in names}
-            rep.op_names = {nm: tuple(self.models[nm].wl.op_names)
-                            for nm in names}
-        if detail == "stalls":
-            rep.stall = {nm: per[nm]["stall"] for nm in names}
-            rep.op_class = {nm: per[nm]["op_class"] for nm in names}
-        return rep
+        rows = int(np.shape(np.atleast_2d(request.idx))[0])
+        with self.tracer.span("eval.call", rows=rows,
+                              bucket=_batch_bucket(rows)):
+            fn = self._fused_fn(request.detail, names)
+            out = _bucketed_call(fn, request.idx,        # ONE fused dispatch
+                                 self.tracer)
+            self.dispatches += 1
+            per = out["per_workload"]
+            detail = request.detail
+            rep = PPAReport(
+                workloads=names, detail=detail, area=out["area"],
+                latency={nm: per[nm]["latency"] for nm in names})
+            if detail in ("ppa", "stalls"):
+                rep.op_time = {nm: per[nm]["op_time"] for nm in names}
+                rep.op_names = {nm: tuple(self.models[nm].wl.op_names)
+                                for nm in names}
+            if detail == "stalls":
+                rep.stall = {nm: per[nm]["stall"] for nm in names}
+                rep.op_class = {nm: per[nm]["op_class"] for nm in names}
+            return rep
 
     def objectives(self, idx: np.ndarray) -> np.ndarray:
         """(n, len(workloads)+1) objectives [*latencies, area], one dispatch."""
@@ -782,7 +791,8 @@ def pair_view(evaluator, names: Tuple[str, str]) -> Evaluator:
     backend = getattr(evaluator, "backend", None)
     return ModelEvaluator({nm: models[nm] for nm in names},
                           tier=evaluator.tier,
-                          backend=backend if backend in _BACKENDS else None)
+                          backend=backend if backend in _BACKENDS else None,
+                          tracer=getattr(evaluator, "tracer", None))
 
 
 def as_evaluator(obj) -> Evaluator:

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import NOOP
 from repro.perfmodel import workload as W
 from repro.perfmodel.designspace import DesignSpace, SPACE
 from repro.perfmodel.hardware import derive_hardware, BYTES_FP16, LINK_LATENCY_S
@@ -137,7 +138,7 @@ def _strip_sinks(tree):
     return tree
 
 
-def _bucketed_call(fn: Callable, idx: np.ndarray):
+def _bucketed_call(fn: Callable, idx: np.ndarray, tracer=NOOP):
     """Pad an index batch to its power-of-two bucket, call a jitted `fn`, and
     slice every output leaf back to the true batch size.
 
@@ -145,14 +146,23 @@ def _bucketed_call(fn: Callable, idx: np.ndarray):
     :class:`~repro.perfmodel.evaluator.ModelEvaluator` dispatch path.
     Sink outputs (keys starting with ``_``) exist only to pin the traced
     executable's materialization and are dropped BEFORE the host transfer.
+    Its phases are ``tracer`` spans: ``eval.upload`` (pad + upload),
+    ``eval.launch`` (the jitted call until it returns) and ``eval.fetch``
+    (one blocking copy per output leaf; ``leaves`` counts them).
     """
-    idx = np.atleast_2d(np.asarray(idx, dtype=np.int32))
-    b = idx.shape[0]
-    bb = _batch_bucket(b)
-    if bb != b:                       # pad with the last row; slice back
-        idx = np.concatenate([idx, np.repeat(idx[-1:], bb - b, axis=0)])
-    out = _strip_sinks(fn(jnp.asarray(idx)))
-    return jax.tree_util.tree_map(lambda v: np.asarray(v)[:b], out)
+    with tracer.span("eval.upload"):
+        idx = np.atleast_2d(np.asarray(idx, dtype=np.int32))
+        b = idx.shape[0]
+        bb = _batch_bucket(b)
+        if bb != b:                   # pad with the last row; slice back
+            idx = np.concatenate([idx, np.repeat(idx[-1:], bb - b, axis=0)])
+        x = jnp.asarray(idx)
+    with tracer.span("eval.launch"):
+        out = _strip_sinks(fn(x))
+    leaves, tree = jax.tree_util.tree_flatten(out)
+    with tracer.span("eval.fetch", leaves=len(leaves)):
+        return jax.tree_util.tree_unflatten(
+            tree, [np.asarray(v)[:b] for v in leaves])
 
 
 def _dominant_class(t: Dict[str, jnp.ndarray]) -> jnp.ndarray:

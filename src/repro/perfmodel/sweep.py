@@ -246,9 +246,14 @@ class SweepEngine:
         Shard the id range over all local devices (no-op on one device).
     registry / tracer:
         Optional :class:`~repro.obs.metrics.MetricsRegistry` and tracer;
-        the engine registers run/chunk/id counters and a per-chunk wall
-        time histogram, and wraps ``run`` / worker spans in trace spans.
-        Defaults: a private registry, and the no-op tracer.
+        the engine registers a chunk counter and a per-chunk wall time
+        histogram, and wraps ``run``, worker spans and each chunk's phases
+        in trace spans (``sweep.chunk`` > ``sweep.filter``, ``sweep.wait``,
+        ``sweep.fetch``, ``sweep.insert`` with ``rows``).  Defaults: a
+        private registry, and the no-op tracer, whose spans still reach a
+        ``jax.profiler`` trace.  The jitted chunk step names its phases
+        with ``jax.named_scope``: ``sweep.decode``, ``sweep.op_terms``,
+        ``sweep.reduce``.
     """
 
     def __init__(self, ttft_model, tpot_model: Optional[RooflineModel] = None,
@@ -419,12 +424,8 @@ class SweepEngine:
 
         self.tracer = tracer if tracer is not None else NOOP
         self.metrics = registry if registry is not None else MetricsRegistry()
-        self._c_runs = self.metrics.counter(
-            "sweep_runs", "completed run() calls")
         self._c_chunks = self.metrics.counter(
             "sweep_chunks", "device chunk steps executed")
-        self._c_ids = self.metrics.counter(
-            "sweep_ids", "design ids evaluated (valid rows)")
         self._h_chunk = self.metrics.histogram(
             "sweep_chunk_s", "wall time per chunk step incl. host reduce (s)")
 
@@ -489,95 +490,104 @@ class SweepEngine:
         if self.backend == "pallas":
             from repro.kernels.ppa_eval.kernel import ppa_eval_fwd
             from repro.kernels.ppa_eval.ref import op_table
-            vals = self.space.decode(idx)
-            dv = jnp.stack([vals[n] for n in self.space.names],
-                           axis=1).astype(jnp.float32)
+            with jax.named_scope("sweep.decode"):
+                vals = self.space.decode(idx)
+                dv = jnp.stack([vals[n] for n in self.space.names],
+                               axis=1).astype(jnp.float32)
             interpret = jax.default_backend() != "tpu"
             block_b = min(256, dv.shape[0])
-            o1 = ppa_eval_fwd(dv, jnp.asarray(op_table(self.ttft_model.wl),
-                                              jnp.float32),
-                              tp=float(self.ttft_model.wl.tp),
-                              block_b=block_b, interpret=interpret)
-            o2 = ppa_eval_fwd(dv, jnp.asarray(op_table(self.tpot_model.wl),
-                                              jnp.float32),
-                              tp=float(self.tpot_model.wl.tp),
-                              block_b=block_b, interpret=interpret)
-            ys = jnp.stack([o1[:, 0], o2[:, 0], o1[:, 5]], axis=1)
-            dom = (jnp.argmax(o1[:, 1:5], axis=1).astype(jnp.int32)
-                   if self.stall_topk else None)
+            with jax.named_scope("sweep.op_terms"):
+                o1 = ppa_eval_fwd(dv, jnp.asarray(op_table(self.ttft_model.wl),
+                                                  jnp.float32),
+                                  tp=float(self.ttft_model.wl.tp),
+                                  block_b=block_b, interpret=interpret)
+                o2 = ppa_eval_fwd(dv, jnp.asarray(op_table(self.tpot_model.wl),
+                                                  jnp.float32),
+                                  tp=float(self.tpot_model.wl.tp),
+                                  block_b=block_b, interpret=interpret)
+                ys = jnp.stack([o1[:, 0], o2[:, 0], o1[:, 5]], axis=1)
+                dom = (jnp.argmax(o1[:, 1:5], axis=1).astype(jnp.int32)
+                       if self.stall_topk else None)
             return ys, dom
-        vals = self.space.decode(idx)
-        hw = derive_hardware(vals)
-        hwb = {kk: vv[:, None] for kk, vv in hw.items()}
+        with jax.named_scope("sweep.decode"):
+            vals = self.space.decode(idx)
+            hw = derive_hardware(vals)
+            hwb = {kk: vv[:, None] for kk, vv in hw.items()}
         detail_t = "stalls" if self.stall_topk else "objectives"
-        out_t = self.ttft_model._workload_batch(hwb, detail_t)
-        out_p = self.tpot_model._workload_batch(hwb, "objectives")
-        ys = jnp.stack([out_t["latency"], out_p["latency"], hw["area_mm2"]],
-                       axis=1)
-        dom = (jnp.argmax(out_t["stall"], axis=1).astype(jnp.int32)
-               if self.stall_topk else None)
+        with jax.named_scope("sweep.op_terms"):
+            out_t = self.ttft_model._workload_batch(hwb, detail_t)
+            out_p = self.tpot_model._workload_batch(hwb, "objectives")
+            ys = jnp.stack([out_t["latency"], out_p["latency"],
+                            hw["area_mm2"]], axis=1)
+            dom = (jnp.argmax(out_t["stall"], axis=1).astype(jnp.int32)
+                   if self.stall_topk else None)
         return ys, dom
 
     def _step_impl(self, carry: Dict[str, jnp.ndarray], start: jnp.ndarray,
                    stop: jnp.ndarray, filt: jnp.ndarray):
         """One donated-carry chunk step: unrank -> evaluate -> reduce."""
-        ids = start + self._iota
-        valid = ids < stop
-        idx = _unrank(jnp.minimum(ids, self.size - 1), self._cards)
+        with jax.named_scope("sweep.decode"):
+            ids = start + self._iota
+            valid = ids < stop
+            idx = _unrank(jnp.minimum(ids, self.size - 1), self._cards)
         ys, dom = self._chunk_eval(idx)                       # (c, 3), (c,)
-        ysm = jnp.where(valid[:, None], ys, jnp.inf)
+        with jax.named_scope("sweep.reduce"):
+            ysm = jnp.where(valid[:, None], ys, jnp.inf)
 
-        # ---- reference-superiority count (exact, streaming) ----
-        ref = jnp.asarray(self.ref_point, ys.dtype)
-        sup = (ysm < ref[None, :]).all(axis=1)
-        n_super = carry["n_super"] + sup.sum(dtype=jnp.int32)
-        n_eval = carry["n_eval"] + valid.sum(dtype=jnp.int32)
+            # ---- reference-superiority count (exact, streaming) ----
+            ref = jnp.asarray(self.ref_point, ys.dtype)
+            sup = (ysm < ref[None, :]).all(axis=1)
+            n_super = carry["n_super"] + sup.sum(dtype=jnp.int32)
+            n_eval = carry["n_eval"] + valid.sum(dtype=jnp.int32)
 
-        # ---- running top-k per objective ----
-        new_vals, new_ids = [], []
-        for o in range(3):                                    # static unroll
-            vals = jnp.concatenate([carry["topk_val"][o], ysm[:, o]])
-            cand = jnp.concatenate([carry["topk_id"][o], ids])
-            neg, sel = jax.lax.top_k(-vals, self.topk)
-            new_vals.append(-neg)
-            new_ids.append(cand[sel])
-        topk_val = jnp.stack(new_vals)
-        topk_id = jnp.stack(new_ids)
-
-        # ---- running top-k per dominant stall class (optional) ----
-        stall_val = stall_id = None
-        if self.stall_topk:
-            if self.stall_rank == "ref":
-                # minimax objective ratio vs the reference (< 1 dominates)
-                lat = (ysm / ref[None, :]).max(axis=1)
-            else:
-                lat = ysm[:, 0]                               # rank by TTFT
+            # ---- running top-k per objective ----
             new_vals, new_ids = [], []
-            for c in range(_N_STALL):                         # static unroll
-                lat_c = jnp.where(dom == c, lat, jnp.inf)
-                vals = jnp.concatenate([carry["stall_topk_val"][c], lat_c])
-                cand = jnp.concatenate([carry["stall_topk_id"][c], ids])
-                neg, sel = jax.lax.top_k(-vals, self.stall_topk)
+            for o in range(3):                                # static unroll
+                vals = jnp.concatenate([carry["topk_val"][o], ysm[:, o]])
+                cand = jnp.concatenate([carry["topk_id"][o], ids])
+                neg, sel = jax.lax.top_k(-vals, self.topk)
                 new_vals.append(-neg)
-                new_ids.append(jnp.where(jnp.isfinite(-neg), cand[sel], -1))
-            stall_val = jnp.stack(new_vals)
-            stall_id = jnp.stack(new_ids)
+                new_ids.append(cand[sel])
+            topk_val = jnp.stack(new_vals)
+            topk_id = jnp.stack(new_ids)
 
-        # ---- streaming Pareto reduction ----
-        # archive filter (synced from host) + chunk-local killer rows:
-        # per-objective minima and smallest log-products dominate most of the
-        # chunk, so the cold-start chunk also reduces on device.
-        L = self.local_filter
-        locals_ = []
-        for o in range(3):
-            _, sel = jax.lax.top_k(-ysm[:, o], L)
+            # ---- running top-k per dominant stall class (optional) ----
+            stall_val = stall_id = None
+            if self.stall_topk:
+                if self.stall_rank == "ref":
+                    # minimax objective ratio vs the reference (< 1 dominates)
+                    lat = (ysm / ref[None, :]).max(axis=1)
+                else:
+                    lat = ysm[:, 0]                           # rank by TTFT
+                new_vals, new_ids = [], []
+                for c in range(_N_STALL):                     # static unroll
+                    lat_c = jnp.where(dom == c, lat, jnp.inf)
+                    vals = jnp.concatenate([carry["stall_topk_val"][c], lat_c])
+                    cand = jnp.concatenate([carry["stall_topk_id"][c], ids])
+                    neg, sel = jax.lax.top_k(-vals, self.stall_topk)
+                    new_vals.append(-neg)
+                    new_ids.append(jnp.where(jnp.isfinite(-neg), cand[sel],
+                                             -1))
+                stall_val = jnp.stack(new_vals)
+                stall_id = jnp.stack(new_ids)
+
+            # ---- streaming Pareto reduction ----
+            # archive filter (synced from host) + chunk-local killer rows:
+            # per-objective minima and smallest log-products dominate most of
+            # the chunk, so the cold-start chunk also reduces on device.
+            L = self.local_filter
+            locals_ = []
+            for o in range(3):
+                _, sel = jax.lax.top_k(-ysm[:, o], L)
+                locals_.append(ysm[sel])
+            _, sel = jax.lax.top_k(
+                -jnp.log(jnp.maximum(ysm, 1e-300)).sum(axis=1), L)
             locals_.append(ysm[sel])
-        _, sel = jax.lax.top_k(-jnp.log(jnp.maximum(ysm, 1e-300)).sum(axis=1), L)
-        locals_.append(ysm[sel])
-        full_filt = jnp.concatenate([filt.astype(ys.dtype)] + locals_, axis=0)
-        dominated = _dominated_on_device(full_filt, ysm)
-        survivor = valid & ~dominated
-        ys_out = jnp.where(survivor[:, None], ys, jnp.inf)
+            full_filt = jnp.concatenate([filt.astype(ys.dtype)] + locals_,
+                                        axis=0)
+            dominated = _dominated_on_device(full_filt, ysm)
+            survivor = valid & ~dominated
+            ys_out = jnp.where(survivor[:, None], ys, jnp.inf)
 
         carry = {"n_super": n_super, "n_eval": n_eval,
                  "topk_val": topk_val, "topk_id": topk_id}
@@ -597,40 +607,42 @@ class SweepEngine:
         ``t_unit`` against the prefill rows) — no per-workload unrolling,
         so both compile time and runtime stay near-flat in W.
         """
-        vals = self.space.decode(idx)
-        hw = derive_hardware(vals)
-        hwb = {kk: vv[:, None] for kk, vv in hw.items()}
+        with jax.named_scope("sweep.decode"):
+            vals = self.space.decode(idx)
+            hw = derive_hardware(vals)
+            hwb = {kk: vv[:, None] for kk, vv in hw.items()}
         stack = self._stack
-        uops = {kk: jnp.asarray(vv) for kk, vv in stack.unique.items()}
-        uops["count"] = jnp.ones(stack.n_unique)
-        t = self._rep_model._op_terms(hwb, ops=uops)
-        # HIGHEST: a DEFAULT-precision f32 dot may run as one bf16 pass on
-        # the TPU (~1e-3 relative error in every scenario latency)
-        lat = jnp.matmul(t["t_unit"], jnp.asarray(self._cmat_all).T,
-                         precision=jax.lax.Precision.HIGHEST)  # (c, 2S)
-        area = hw["area_mm2"]
-        S = len(self.scenarios)
-        ys = jnp.stack([lat[:, 0::2], lat[:, 1::2],
-                        jnp.broadcast_to(area[:, None],
-                                         (idx.shape[0], S))], axis=2)
-        dom = None
-        if self.stall_topk:
-            # a SECOND op-term pass statically restricted to prefill-used
-            # rows: consuming t_compute/t_memory/t_comm out of the full
-            # union pass would force XLA to re-materialize its big (c, U)
-            # intermediates — recomputing the small (c, P) chain is 2x
-            # cheaper than widening the first pass's fusion
-            uop2 = {kk: jnp.asarray(vv[self._stall_cols])
-                    for kk, vv in stack.unique.items()}
-            uop2["count"] = jnp.ones(len(self._stall_cols))
-            t2 = self._rep_model._op_terms(hwb, ops=uop2)
-            dom_g = _dominant_class(t2)                     # (c, P)
-            cp = jnp.asarray(self._cmat_prefill).T          # (P, S)
-            stall = jnp.stack(
-                [jnp.matmul(jnp.where(dom_g == k, t2["t_unit"], 0.0), cp,
-                            precision=jax.lax.Precision.HIGHEST)
-                 for k in range(_N_STALL)], axis=2)         # (c, S, 4)
-            dom = jnp.argmax(stall, axis=2).astype(jnp.int32)
+        with jax.named_scope("sweep.op_terms"):
+            uops = {kk: jnp.asarray(vv) for kk, vv in stack.unique.items()}
+            uops["count"] = jnp.ones(stack.n_unique)
+            t = self._rep_model._op_terms(hwb, ops=uops)
+            # HIGHEST: a DEFAULT-precision f32 dot may run as one bf16 pass on
+            # the TPU (~1e-3 relative error in every scenario latency)
+            lat = jnp.matmul(t["t_unit"], jnp.asarray(self._cmat_all).T,
+                             precision=jax.lax.Precision.HIGHEST)  # (c, 2S)
+            area = hw["area_mm2"]
+            S = len(self.scenarios)
+            ys = jnp.stack([lat[:, 0::2], lat[:, 1::2],
+                            jnp.broadcast_to(area[:, None],
+                                             (idx.shape[0], S))], axis=2)
+            dom = None
+            if self.stall_topk:
+                # a SECOND op-term pass statically restricted to prefill-used
+                # rows: consuming t_compute/t_memory/t_comm out of the full
+                # union pass would force XLA to re-materialize its big (c, U)
+                # intermediates — recomputing the small (c, P) chain is 2x
+                # cheaper than widening the first pass's fusion
+                uop2 = {kk: jnp.asarray(vv[self._stall_cols])
+                        for kk, vv in stack.unique.items()}
+                uop2["count"] = jnp.ones(len(self._stall_cols))
+                t2 = self._rep_model._op_terms(hwb, ops=uop2)
+                dom_g = _dominant_class(t2)                     # (c, P)
+                cp = jnp.asarray(self._cmat_prefill).T          # (P, S)
+                stall = jnp.stack(
+                    [jnp.matmul(jnp.where(dom_g == k, t2["t_unit"], 0.0), cp,
+                                precision=jax.lax.Precision.HIGHEST)
+                     for k in range(_N_STALL)], axis=2)         # (c, S, 4)
+                dom = jnp.argmax(stall, axis=2).astype(jnp.int32)
         return ys, dom
 
     def _robust_objectives(self, ys_s: jnp.ndarray) -> jnp.ndarray:
@@ -657,78 +669,81 @@ class SweepEngine:
         """
         S = len(self.scenarios)
         S1, k, c = S + 1, self.topk, self.chunk_size
-        ids = start + self._iota
-        valid = ids < stop
-        idx = _unrank(jnp.minimum(ids, self.size - 1), self._cards)
+        with jax.named_scope("sweep.decode"):
+            ids = start + self._iota
+            valid = ids < stop
+            idx = _unrank(jnp.minimum(ids, self.size - 1), self._cards)
         ys_s, dom = self._chunk_eval_portfolio(idx)       # (c,S,3), (c,S)
-        ys_r = self._robust_objectives(ys_s)              # (c,3)
-        ys_all = jnp.concatenate([ys_s, ys_r[:, None, :]], axis=1)
-        ysm = jnp.where(valid[:, None, None], ys_all, jnp.inf)
+        with jax.named_scope("sweep.reduce"):
+            ys_r = self._robust_objectives(ys_s)              # (c,3)
+            ys_all = jnp.concatenate([ys_s, ys_r[:, None, :]], axis=1)
+            ysm = jnp.where(valid[:, None, None], ys_all, jnp.inf)
 
-        # ---- per-group reference-superiority counts ----
-        refs_all = jnp.concatenate(
-            [jnp.asarray(self.ref_points, ys_all.dtype),
-             jnp.asarray(self.ref_point, ys_all.dtype)[None, :]], axis=0)
-        sup = (ysm < refs_all[None, :, :]).all(axis=2)    # (c, S1)
-        n_super = carry["n_super"] + sup.sum(axis=0, dtype=jnp.int32)
-        n_eval = carry["n_eval"] + valid.sum(dtype=jnp.int32)
+            # ---- per-group reference-superiority counts ----
+            refs_all = jnp.concatenate(
+                [jnp.asarray(self.ref_points, ys_all.dtype),
+                 jnp.asarray(self.ref_point, ys_all.dtype)[None, :]], axis=0)
+            sup = (ysm < refs_all[None, :, :]).all(axis=2)    # (c, S1)
+            n_super = carry["n_super"] + sup.sum(axis=0, dtype=jnp.int32)
+            n_eval = carry["n_eval"] + valid.sum(dtype=jnp.int32)
 
-        # ---- running top-k, batched over (S1 x 3) rows ----
-        ysm_rows = jnp.moveaxis(ysm, 0, 2)                # (S1, 3, c)
-        vals = jnp.concatenate(
-            [carry["topk_val"].reshape(S1 * 3, k),
-             ysm_rows.reshape(S1 * 3, c)], axis=1)
-        cand = jnp.concatenate(
-            [carry["topk_id"].reshape(S1 * 3, k),
-             jnp.broadcast_to(ids[None, :], (S1 * 3, c))], axis=1)
-        neg, sel = jax.lax.top_k(-vals, k)
-        topk_val = (-neg).reshape(S1, 3, k)
-        topk_id = jnp.take_along_axis(cand, sel, axis=1).reshape(S1, 3, k)
-
-        # ---- per-scenario stall-class top-k (optional), batched ----
-        stall_val = stall_id = None
-        if self.stall_topk:
-            sk = self.stall_topk
-            refs = refs_all[:S]
-            if self.stall_rank == "ref":
-                rank = (ysm[:, :S, :] / refs[None, :, :]).max(axis=2)
-            else:
-                rank = ysm[:, :S, 0]                      # scenario prefill
-            hit = dom[:, :, None] == jnp.arange(_N_STALL)[None, None, :]
-            masked = jnp.where(hit, rank[:, :, None], jnp.inf)  # (c, S, 4)
-            rows = jnp.moveaxis(masked, 0, 2).reshape(S * _N_STALL, c)
+            # ---- running top-k, batched over (S1 x 3) rows ----
+            ysm_rows = jnp.moveaxis(ysm, 0, 2)                # (S1, 3, c)
             vals = jnp.concatenate(
-                [carry["stall_topk_val"].reshape(S * _N_STALL, sk), rows],
-                axis=1)
+                [carry["topk_val"].reshape(S1 * 3, k),
+                 ysm_rows.reshape(S1 * 3, c)], axis=1)
             cand = jnp.concatenate(
-                [carry["stall_topk_id"].reshape(S * _N_STALL, sk),
-                 jnp.broadcast_to(ids[None, :], (S * _N_STALL, c))], axis=1)
-            neg, sel = jax.lax.top_k(-vals, sk)
-            stall_val = (-neg).reshape(S, _N_STALL, sk)
-            stall_id = jnp.where(jnp.isfinite(-neg),
-                                 jnp.take_along_axis(cand, sel, axis=1),
-                                 -1).reshape(S, _N_STALL, sk)
+                [carry["topk_id"].reshape(S1 * 3, k),
+                 jnp.broadcast_to(ids[None, :], (S1 * 3, c))], axis=1)
+            neg, sel = jax.lax.top_k(-vals, k)
+            topk_val = (-neg).reshape(S1, 3, k)
+            topk_id = jnp.take_along_axis(cand, sel, axis=1).reshape(S1, 3, k)
 
-        # ---- streaming Pareto reduction, batched over all S1 groups ----
-        # chunk-local killer rows: each group's per-objective minima plus
-        # its best reference-normalized sum (4 rows/group, one argmin pass)
-        normsum = (ysm / refs_all[None, :, :]).sum(axis=2)     # (c, S1)
-        keys = jnp.concatenate([ysm, normsum[:, :, None]], axis=2)
-        sel = jnp.argmin(keys, axis=0)                         # (S1, 4)
-        ysm_t = jnp.moveaxis(ysm, 0, 1)                        # (S1, c, 3)
-        locals_ = jnp.take_along_axis(ysm_t, sel[:, :, None], axis=1)
-        full_filt = jnp.concatenate(
-            [filt.astype(ys_all.dtype), locals_], axis=1)      # (S1, f+4, 3)
-        all_le = jnp.ones((c, S1, full_filt.shape[1]), bool)
-        any_lt = jnp.zeros_like(all_le)
-        for j in range(3):
-            fj = full_filt[None, :, :, j]
-            yj = ysm[:, :, j][:, :, None]
-            all_le &= fj <= yj
-            any_lt |= fj < yj
-        dominated = (all_le & any_lt).any(axis=2)              # (c, S1)
-        survivor = valid[:, None] & ~dominated
-        ys_out = jnp.where(survivor[:, :, None], ys_all, jnp.inf)
+            # ---- per-scenario stall-class top-k (optional), batched ----
+            stall_val = stall_id = None
+            if self.stall_topk:
+                sk = self.stall_topk
+                refs = refs_all[:S]
+                if self.stall_rank == "ref":
+                    rank = (ysm[:, :S, :] / refs[None, :, :]).max(axis=2)
+                else:
+                    rank = ysm[:, :S, 0]                  # scenario prefill
+                hit = dom[:, :, None] == jnp.arange(_N_STALL)[None, None, :]
+                masked = jnp.where(hit, rank[:, :, None], jnp.inf)  # (c, S, 4)
+                rows = jnp.moveaxis(masked, 0, 2).reshape(S * _N_STALL, c)
+                vals = jnp.concatenate(
+                    [carry["stall_topk_val"].reshape(S * _N_STALL, sk), rows],
+                    axis=1)
+                cand = jnp.concatenate(
+                    [carry["stall_topk_id"].reshape(S * _N_STALL, sk),
+                     jnp.broadcast_to(ids[None, :], (S * _N_STALL, c))],
+                    axis=1)
+                neg, sel = jax.lax.top_k(-vals, sk)
+                stall_val = (-neg).reshape(S, _N_STALL, sk)
+                stall_id = jnp.where(jnp.isfinite(-neg),
+                                     jnp.take_along_axis(cand, sel, axis=1),
+                                     -1).reshape(S, _N_STALL, sk)
+
+            # ---- streaming Pareto reduction, batched over all S1 groups ----
+            # chunk-local killer rows: each group's per-objective minima plus
+            # its best reference-normalized sum (4 rows/group, one argmin pass)
+            normsum = (ysm / refs_all[None, :, :]).sum(axis=2)     # (c, S1)
+            keys = jnp.concatenate([ysm, normsum[:, :, None]], axis=2)
+            sel = jnp.argmin(keys, axis=0)                         # (S1, 4)
+            ysm_t = jnp.moveaxis(ysm, 0, 1)                        # (S1, c, 3)
+            locals_ = jnp.take_along_axis(ysm_t, sel[:, :, None], axis=1)
+            full_filt = jnp.concatenate(
+                [filt.astype(ys_all.dtype), locals_], axis=1)  # (S1, f+4, 3)
+            all_le = jnp.ones((c, S1, full_filt.shape[1]), bool)
+            any_lt = jnp.zeros_like(all_le)
+            for j in range(3):
+                fj = full_filt[None, :, :, j]
+                yj = ysm[:, :, j][:, :, None]
+                all_le &= fj <= yj
+                any_lt |= fj < yj
+            dominated = (all_le & any_lt).any(axis=2)              # (c, S1)
+            survivor = valid[:, None] & ~dominated
+            ys_out = jnp.where(survivor[:, :, None], ys_all, jnp.inf)
 
         carry = {"n_super": n_super, "n_eval": n_eval,
                  "topk_val": topk_val, "topk_id": topk_id}
@@ -887,7 +902,6 @@ class SweepEngine:
                             fault_plan=fault_plan, span_retry=span_retry,
                             trace_parent=parent))
                     states = [f.result() for f in futs]
-            self._c_runs.inc()
         return self._reduce_states(states, time.perf_counter() - t0)
 
     def _run_span(self, worker: int, start: int, stop: int, *,
@@ -976,6 +990,7 @@ class SweepEngine:
                                          else [state["archive"]])
         carry = state["carry"]
         n_eval_resumed = int(carry["n_eval"])
+        tr = self.tracer
         t0 = time.perf_counter()
         chunk_i = 0
         while state["next"] < stop:
@@ -988,33 +1003,38 @@ class SweepEngine:
                 if ev is not None and ev.kind == "slow":
                     time.sleep(ev.delay_s)
             t_chunk = time.perf_counter()
-            s = state["next"]
-            rows = self._pf_rows if self._portfolio else None
-            filt = np.stack([self._filter_from_archive(a, rows)
-                             for a in archives])
-            filt = jnp.asarray(filt if self._portfolio else filt[0])
-            # ids >= stop are masked invalid on device, so a partial final
-            # chunk (or a truncated-range sweep) stays exact for free.
-            carry, survivor, ys_out, ids = self._step(
-                carry, jnp.int32(s), jnp.int32(stop), filt)
-            mask = np.asarray(survivor)       # (c,) or (c, S+1)
-            if mask.any():
-                ys_np, ids_np = np.asarray(ys_out), np.asarray(ids)
-                if self._portfolio:
-                    for g, a in enumerate(archives):
-                        mg = mask[:, g]
-                        if mg.any():
-                            a.insert(ys_np[mg, g, :], ids=ids_np[mg])
-                else:
-                    archives[0].insert(ys_np[mask], ids=ids_np[mask])
-            # clamp to `stop`: ids beyond it were masked invalid, and a later
-            # resume with a larger stop must re-visit them
-            state["next"] = min(s + self.chunk_size, stop)
-            state["carry"] = carry
-            chunk_i += 1
-            self._c_chunks.inc()
-            self._c_ids.inc(state["next"] - s)
-            self._h_chunk.observe(time.perf_counter() - t_chunk)
+            with tr.span("sweep.chunk"):
+                s = state["next"]
+                rows = self._pf_rows if self._portfolio else None
+                with tr.span("sweep.filter"):
+                    filt = np.stack([self._filter_from_archive(a, rows)
+                                     for a in archives])
+                    filt = jnp.asarray(filt if self._portfolio else filt[0])
+                # ids >= stop are masked invalid on device, so a partial
+                # final chunk (or a truncated-range sweep) stays exact.
+                carry, survivor, ys_out, ids = self._step(
+                    carry, jnp.int32(s), jnp.int32(stop), filt)
+                with tr.span("sweep.wait"):
+                    mask = np.asarray(survivor)       # (c,) or (c, S+1)
+                n_rows = int(np.count_nonzero(mask))  # summed over groups
+                if n_rows:
+                    with tr.span("sweep.fetch"):
+                        ys_np, ids_np = np.asarray(ys_out), np.asarray(ids)
+                    with tr.span("sweep.insert", rows=n_rows):
+                        if self._portfolio:
+                            for g, a in enumerate(archives):
+                                mg = mask[:, g]
+                                if mg.any():
+                                    a.insert(ys_np[mg, g, :], ids=ids_np[mg])
+                        else:
+                            archives[0].insert(ys_np[mask], ids=ids_np[mask])
+                # clamp to `stop`: ids beyond it were masked invalid, and a
+                # later resume with a larger stop must re-visit them
+                state["next"] = min(s + self.chunk_size, stop)
+                state["carry"] = carry
+                chunk_i += 1
+                self._c_chunks.inc()
+                self._h_chunk.observe(time.perf_counter() - t_chunk)
             if progress:
                 done = min(state["next"], stop)
                 # rate counts only ids swept in THIS process (resumed ids
@@ -1199,9 +1219,7 @@ class SweepEngine:
     def telemetry(self) -> dict:
         """Registry view of the engine's streaming counters."""
         return {
-            "runs": int(self._c_runs.value()),
             "chunks": int(self._c_chunks.value()),
-            "ids": int(self._c_ids.value()),
             "chunk_s": self._h_chunk.stats(),
         }
 

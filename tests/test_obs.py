@@ -175,6 +175,63 @@ def test_noop_tracer_is_inert():
     assert NOOP.spans() == [] and NOOP.drain() == []
 
 
+# -------------------------------------------------------- profiler bridge
+def _by_name(events):
+    return {name: (line, s, e, stats) for line, name, s, e, stats in events}
+
+
+@pytest.mark.parametrize("kind", ["noop", "recording"])
+def test_spans_land_on_the_profiler_host_plane(profile, kind):
+    """Every span() block is a profiler annotation with its name, nesting
+    and integer attributes, whether or not the tracer records."""
+    tr = NOOP if kind == "noop" else Tracer(proc="p")
+    with profile.trace():
+        with tr.span("t.outer", rows=17, label="a"):
+            with tr.span("t.inner", leaves=3):
+                pass
+    ev = _by_name(profile.host_events(("t.",)))
+    line_o, s_o, e_o, st_o = ev["t.outer"]
+    line_i, s_i, e_i, st_i = ev["t.inner"]
+    assert line_o == line_i and s_o <= s_i <= e_i <= e_o
+    assert st_o["rows"] == 17 and isinstance(st_o["rows"], int)
+    assert st_o["label"] == "a"
+    assert st_i["leaves"] == 3
+    if kind == "recording":                    # its own buffer as before
+        got = {s.name: s for s in tr.spans()}
+        assert got["t.outer"].attrs == {"rows": 17, "label": "a"}
+        assert got["t.inner"].parent_id == got["t.outer"].span_id
+
+
+def test_detached_span_is_not_on_the_profiler(profile):
+    tr = Tracer(proc="p")
+    with profile.trace():
+        sp = tr.start("t.detached", detached=True, rows=1)
+        with tr.span("t.attached"):
+            pass
+        tr.finish(sp)
+    names = {e[1] for e in profile.host_events(("t.",))}
+    assert names == {"t.attached"}
+    assert {s.name for s in tr.spans()} == {"t.detached", "t.attached"}
+
+
+def test_evaluate_spans_its_phases_and_counts_fetched_leaves(profile):
+    """A stalls report of the paper pair copies 15 leaves back: area plus
+    seven per workload."""
+    ev = _fresh("target")
+    idx = SPACE.sample(RNG, 5)
+    ev.evaluate(EvalRequest(idx, detail="stalls"))         # compile outside
+    with profile.trace():
+        ev.evaluate(EvalRequest(idx, detail="stalls"))
+    events = profile.host_events(("eval.",))
+    got = _by_name(events)
+    assert [e[1] for e in events] == ["eval.call", "eval.upload",
+                                      "eval.launch", "eval.fetch"]
+    assert got["eval.call"][3] == {"rows": 5, "bucket": 8}
+    assert got["eval.fetch"][3] == {"leaves": 15}
+    _, s0, e0, _ = got["eval.call"]
+    assert all(s0 <= s <= e <= e0 for _, s, e, _ in got.values())
+
+
 # ------------------------------------------------------------------ export
 def test_trace_events_schema_and_tree_checks():
     tr = Tracer(clock=ManualClock(), proc="main")

@@ -6,15 +6,17 @@ streaming ``ParetoArchive`` equals the batch front, and a truncated
 full-space sweep reproduces brute-force evaluation exactly.
 """
 import os
+import re
 
 import numpy as np
 import pytest
 
 from repro.core.pareto import (ParetoArchive, dominates_ref, hypervolume,
                                pareto_front, pareto_mask)
-from repro.perfmodel import get_evaluator
+from repro.perfmodel import get_evaluator, make_evaluator
 from repro.perfmodel.designspace import SPACE
 from repro.perfmodel.sweep import SweepEngine, _unrank
+from repro.perfmodel.workload import zoo_suite
 
 SUBSPACE = 50_000
 
@@ -39,6 +41,13 @@ def _reference_pareto_mask(y):
 @pytest.fixture(scope="module")
 def engine():
     return SweepEngine(get_evaluator("proxy"), chunk_size=16_384)
+
+
+@pytest.fixture(scope="module")
+def portfolio_engine():
+    wls, scen = zoo_suite(archs=("qwen2-moe-a2.7b", "rwkv6-7b"), smoke=True)
+    return SweepEngine(make_evaluator(wls, tier="proxy", scenarios=scen),
+                       chunk_size=4_096, stall_topk=2)
 
 
 # ------------------------------------------------------------ pareto_mask
@@ -184,7 +193,7 @@ def test_sweep_checkpoint_rejects_mismatched_config(engine, tmp_path):
 
 _SHARDED_CHECK = """
 import numpy as np, jax
-from repro.perfmodel import get_evaluator
+from repro.perfmodel import get_evaluator, make_evaluator
 from repro.perfmodel.sweep import SweepEngine
 assert len(jax.devices()) == 4
 ev = get_evaluator("proxy")
@@ -212,6 +221,46 @@ def test_sharded_sweep_identical_on_four_devices():
     r = subprocess.run([sys.executable, "-c", _SHARDED_CHECK], env=env,
                        capture_output=True, text=True, timeout=600, cwd=repo)
     assert r.returncode == 0, r.stderr[-3000:]
+
+
+# ------------------------------------------------------ spans and scopes
+@pytest.mark.parametrize("kind", ["paper", "portfolio"])
+def test_sweep_spans_every_chunk_phase(engine, portfolio_engine, profile,
+                                       kind):
+    """One chunk, filter and wait span per chunk, each inside its chunk;
+    the insert spans' rows sum to the rows the archives received."""
+    eng = engine if kind == "paper" else portfolio_engine
+    stop = 3 * eng.chunk_size + 100                       # four chunks
+    eng.run(0, stop)                                      # compile outside
+    with profile.trace():
+        state = eng._run_range(0, stop)
+    events = profile.host_events(("sweep.",))
+    names = [e[1] for e in events]
+    for name in ("sweep.chunk", "sweep.filter", "sweep.wait"):
+        assert names.count(name) == 4, name
+    assert names.count("sweep.fetch") == names.count("sweep.insert") >= 1
+    chunks = [(s, e) for _, n, s, e, _ in events if n == "sweep.chunk"]
+    for _, n, s, e, _ in events:
+        assert any(s0 <= s <= e <= e0 for s0, e0 in chunks), n
+    rows = sum(st["rows"] for _, n, _, _, st in events
+               if n == "sweep.insert")
+    assert rows == sum(a.n_seen for a in eng._archives_of(state)) > 0
+
+
+@pytest.mark.parametrize("kind", ["paper", "portfolio"])
+def test_chunk_step_names_its_phases_in_hlo_metadata(engine,
+                                                     portfolio_engine, kind):
+    eng = engine if kind == "paper" else portfolio_engine
+    st = eng._fresh_state(0)
+    rows = eng._pf_rows if eng._portfolio else None
+    filt = np.stack([eng._filter_from_archive(a, rows)
+                     for a in eng._archives_of(st)])
+    text = eng._step.lower(
+        st["carry"], np.int32(0), np.int32(eng.chunk_size),
+        filt if eng._portfolio else filt[0]).compile().as_text()
+    for scope in ("sweep.decode", "sweep.op_terms", "sweep.reduce"):
+        assert re.search(r'op_name="[^"]*/' + re.escape(scope) + "/",
+                         text), scope
 
 
 def test_pallas_backend_rejects_compass_models():
