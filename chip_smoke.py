@@ -457,7 +457,7 @@ def phase_kernel(sm):
     sm.fact("kernel", "evaluator_backend", pal.backend)
     rng = np.random.default_rng(3)
     for b in (32, 4096):
-        fn = pal._fused_fn("objectives", pal.workloads)
+        fn = pal._fused_fn("objectives", pal.workloads).jitted
         hlo = compiled_hlo(fn, jnp.zeros((b, SPACE.n_params), jnp.int32))
         sm.check("kernel", f"evaluator_b{b}_tpu_custom_call",
                  "tpu_custom_call" in hlo)

@@ -51,7 +51,7 @@ from repro.obs.trace import NOOP
 from repro.perfmodel.critical_path import StallReport, build_report
 from repro.perfmodel.designspace import DesignSpace, SPACE
 from repro.perfmodel.hardware import derive_hardware
-from repro.perfmodel.roofline import (RooflineModel, _JIT_CACHE,
+from repro.perfmodel.roofline import (PackedFn, RooflineModel, _JIT_CACHE,
                                       _batch_bucket, _bucketed_call,
                                       _space_key,
                                       _workload_fingerprint,
@@ -350,9 +350,16 @@ class ModelEvaluator:
     derives the hardware spec once, and computes every workload's op terms —
     a single device dispatch per :meth:`evaluate` call regardless of the
     number of workloads or the detail level.  ``dispatches`` counts them
-    (the DSE loop asserts one per step).  ``tracer`` (default the no-op
-    tracer) spans each call as ``eval.call`` (``rows``, ``bucket``) over
-    ``eval.upload``, ``eval.launch`` and ``eval.fetch``.
+    (the DSE loop asserts one per step).  The same executable packs the
+    report (:class:`~repro.perfmodel.roofline.PackedFn`): every leaf goes
+    through one optimization barrier with the ``_``-keyed sinks, so the
+    computation before it fuses as it would with the leaves as outputs and
+    the report stays bit-identical, and the leaves leave the device as one
+    uint32 buffer in ONE transfer.  The sinks stay unfetched outputs so
+    that ``t_op`` is still materialized before the latency reduce.
+    ``tracer`` (default the no-op tracer) spans each call as ``eval.call``
+    (``rows``, ``bucket``) over ``eval.upload``, ``eval.launch`` and
+    ``eval.fetch`` (``leaves``, ``copies``).
     """
 
     def __init__(self, models: Mapping[str, RooflineModel], *,
@@ -383,7 +390,7 @@ class ModelEvaluator:
         self.stacked = eligible if stacked is None else bool(stacked)
         self.dispatches = 0            # fused jitted dispatch count
         self.tracer = tracer if tracer is not None else NOOP
-        self._fns: Dict[tuple, Callable] = {}
+        self._fns: Dict[tuple, PackedFn] = {}
         self._stacks: Dict[Tuple[str, ...], WorkloadStack] = {}
 
     # -- identity ------------------------------------------------------
@@ -408,7 +415,7 @@ class ModelEvaluator:
                       for nm, m in self.models.items() if nm in names))
 
     # -- fused traced path ---------------------------------------------
-    def _fused_fn(self, detail: str, names: Tuple[str, ...]) -> Callable:
+    def _fused_fn(self, detail: str, names: Tuple[str, ...]) -> PackedFn:
         local = self._fns.get((detail, names))
         if local is not None:
             return local
@@ -417,9 +424,9 @@ class ModelEvaluator:
         if fn is None:
             if self.backend != "roofline" and _backend(self.backend).kernel \
                     and detail == "objectives":
-                fn = jax.jit(self._build_kernel_objectives(names))
+                fn = PackedFn(self._build_kernel_objectives(names))
             else:
-                fn = jax.jit(self._build_traced(detail, names))
+                fn = PackedFn(self._build_traced(detail, names))
             _JIT_CACHE[key] = fn
         self._fns[(detail, names)] = fn
         return fn

@@ -8,6 +8,8 @@ one fused dispatch; the pre-PR-2 deprecation shims are GONE; the oracle
 tier normalizes PHV against the exhaustive front; and the sweep's
 per-stall-class top-k matches brute force.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -17,8 +19,10 @@ from repro.perfmodel import (CompassModel, EvalRequest, ModelEvaluator,
                              get_evaluator, make_evaluator,
                              gpt3_layer_prefill, gpt3_layer_decode)
 from repro.perfmodel.designspace import SPACE, A100_REFERENCE
-from repro.perfmodel.evaluator import (as_evaluator, evaluator_for_model,
-                                       resolve_backend)
+from repro.perfmodel.evaluator import (DETAILS, as_evaluator,
+                                       evaluator_for_model, resolve_backend)
+from repro.perfmodel.roofline import (PackedFn, _batch_bucket, _bucketed_call,
+                                      _split_sinks)
 from repro.perfmodel.sweep import SweepEngine
 
 RNG = np.random.default_rng(11)
@@ -106,6 +110,91 @@ def test_stall_report_matches_attribute_stalls(tier_setup):
     assert rep.dominant == legacy.dominant
     assert rep.latency == pytest.approx(legacy.latency, rel=0)
     assert rep.top_ops == legacy.top_ops
+
+
+# ------------------------------------------------- one-copy packed fetch
+@pytest.fixture(scope="module")
+def zoo_evaluator():
+    """A stacked zoo-portfolio evaluator."""
+    from repro.perfmodel.workload import zoo_suite
+    wls, scen = zoo_suite(archs=("qwen2-moe-a2.7b", "rwkv6-7b"), smoke=True,
+                          batch=4)
+    ev = make_evaluator(wls, tier="proxy", scenarios=scen)
+    assert ev.stacked
+    return ev
+
+
+def _per_leaf_report(fn, idx):
+    """The report as fetched before packing: the jitted fused function, its
+    sinks stripped, one ``np.asarray`` per leaf, sliced to the batch."""
+    b = idx.shape[0]
+    x = np.concatenate([idx, np.repeat(idx[-1:], _batch_bucket(b) - b, 0)])
+    out, _ = _split_sinks(jax.jit(fn)(x))
+    return jax.tree_util.tree_map(lambda v: np.asarray(v)[:b], out)
+
+
+PACK_CASES = ([(t, d, b) for t in ("proxy", "target") for d in DETAILS
+               for b in (1, 5, 8, 33)]
+              + [("zoo", d, 33) for d in DETAILS])
+
+
+@pytest.mark.parametrize("kind,detail,b", PACK_CASES,
+                         ids=[f"{k}-{d}-b{b}" for k, d, b in PACK_CASES])
+def test_packed_fetch_bit_identical_to_per_leaf_copies(kind, detail, b,
+                                                       request):
+    """The one-transfer packed fetch hands back exactly the tree the
+    per-leaf copies did: same structure, every leaf equal bit for bit
+    with its dtype, cut to the true batch (b=33 pads to the 64 bucket)."""
+    ev = (request.getfixturevalue("zoo_evaluator") if kind == "zoo"
+          else get_evaluator(kind))
+    idx = SPACE.sample(np.random.default_rng(100 + b), b)
+    packed = ev._fused_fn(detail, ev.workloads)
+    got = _bucketed_call(packed, idx)
+    want = _per_leaf_report(packed.fn, idx)
+    g, gt = jax.tree_util.tree_flatten(got)
+    w, wt = jax.tree_util.tree_flatten(want)
+    assert gt == wt
+    for a, r in zip(g, w):
+        assert a.dtype == r.dtype and a.shape == r.shape
+        assert np.array_equal(a, r)
+        assert not a.flags.writeable
+    rep = ev.evaluate(EvalRequest(idx, detail=detail))
+    per = want["per_workload"]
+    assert np.array_equal(rep.area, want["area"])
+    for nm in ev.workloads:
+        assert np.array_equal(rep.latency[nm], per[nm]["latency"])
+        assert not rep.latency[nm].flags.writeable
+        if detail == "stalls":
+            assert rep.op_class[nm].dtype == np.int32
+            assert np.array_equal(rep.op_class[nm], per[nm]["op_class"])
+            assert np.array_equal(rep.stall[nm], per[nm]["stall"])
+
+
+def test_packed_fn_carries_bool_leaves_and_keeps_sinks_on_device():
+    fn = PackedFn(lambda x: {"hit": x[:, 0] > 2,
+                             "v": x.astype(jnp.float32) * 0.5,
+                             "_sink": x * 2})
+    idx = SPACE.sample(np.random.default_rng(4), 5)
+    got = _bucketed_call(fn, idx)
+    assert set(got) == {"hit", "v"}
+    assert got["hit"].dtype == np.bool_
+    assert np.array_equal(got["hit"], idx[:, 0] > 2)
+    assert np.array_equal(got["v"], idx.astype(np.float32) * 0.5)
+    assert not got["hit"].flags.writeable
+    buf, sinks = fn.jitted(jnp.asarray(idx[:1].repeat(8, 0)))
+    assert buf.dtype == jnp.uint32 and buf.ndim == 1
+    assert len(sinks) == 1 and sinks[0].shape == (8, SPACE.n_params)
+
+
+def test_packed_fn_rejects_unpackable_leaves():
+    idx = SPACE.sample(np.random.default_rng(4), 3)
+    with jax.enable_x64(True):
+        wide = PackedFn(lambda x: {"v": x.astype(jnp.float64)})
+        with pytest.raises(TypeError, match="4-byte and bool"):
+            _bucketed_call(wide, idx)
+    flat = PackedFn(lambda x: {"v": x.sum()})
+    with pytest.raises(ValueError, match="leading batch axis"):
+        _bucketed_call(flat, idx)
 
 
 # ------------------------------------------------------- backend registry

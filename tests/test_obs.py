@@ -215,8 +215,8 @@ def test_detached_span_is_not_on_the_profiler(profile):
 
 
 def test_evaluate_spans_its_phases_and_counts_fetched_leaves(profile):
-    """A stalls report of the paper pair copies 15 leaves back: area plus
-    seven per workload."""
+    """A stalls report of the paper pair hands back 15 leaves: area plus
+    seven per workload, unpacked from one device-to-host copy."""
     ev = _fresh("target")
     idx = SPACE.sample(RNG, 5)
     ev.evaluate(EvalRequest(idx, detail="stalls"))         # compile outside
@@ -227,7 +227,7 @@ def test_evaluate_spans_its_phases_and_counts_fetched_leaves(profile):
     assert [e[1] for e in events] == ["eval.call", "eval.upload",
                                       "eval.launch", "eval.fetch"]
     assert got["eval.call"][3] == {"rows": 5, "bucket": 8}
-    assert got["eval.fetch"][3] == {"leaves": 15}
+    assert got["eval.fetch"][3] == {"leaves": 15, "copies": 1}
     _, s0, e0, _ = got["eval.call"]
     assert all(s0 <= s <= e <= e0 for _, s, e, _ in got.values())
 
