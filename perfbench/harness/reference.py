@@ -7,7 +7,9 @@ hardware, the operator graphs of each workload, the per-op roofline terms
 of the proxy tier and the LLMCompass-style knobs of the target tier, the
 stall attribution, and the latency / area reduction.  It imports nothing of
 the program under test, and takes nothing it made: the operator graphs are
-built here from the widths in the configuration file.
+built here from the widths in the configuration file.  An architecture
+family this module does not restate itself (``OWN_FAMILIES``) is restated
+by its own file, ``families/<family>.py``, under the same rule.
 
 One function body serves two precisions: float64 (the reference, under
 ``jax.enable_x64``, on the host CPU), and bfloat16 (the control, which must
@@ -23,6 +25,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from harness import spec
 
 # ---------------------------------------------------------------- the space
 # LUMINA Table 1: (parameter, choices), last parameter fastest-varying in
@@ -177,8 +181,8 @@ def gpt3_decode(w: Dict, batch: int, seq: int, out_pos: int,
     return g
 
 
-def _attention(g: Graph, batch, q_len, kv_len, d, n_heads, n_kv, head_dim,
-               tp, count, decode):
+def attention(g: Graph, batch, q_len, kv_len, d, n_heads, n_kv, head_dim,
+              tp, count, decode):
     hl = max(1, n_heads // tp)
     kvl = max(1, n_kv // tp)
     M = batch * q_len
@@ -200,14 +204,14 @@ def _attention(g: Graph, batch, q_len, kv_len, d, n_heads, n_kv, head_dim,
     g.allreduce(M * d, count=count)
 
 
-def _ffn(g: Graph, M, d, d_ff, tp, gated, count):
+def ffn(g: Graph, M, d, d_ff, tp, gated, count):
     g.matmul(M, d, (2 if gated else 1) * d_ff // tp, count=count)
     g.vector(M * d_ff // tp, 8.0, count=count)
     g.matmul(M, d_ff // tp, d, count=count)
     g.allreduce(M * d, count=count)
 
 
-def _moe(g: Graph, M, d, expert_ff, n_experts, top_k, n_shared, tp, count):
+def moe(g: Graph, M, d, expert_ff, n_experts, top_k, n_shared, tp, count):
     """Router, top-k expert FFNs over an expert-parallel group of ``tp``,
     all-to-all dispatch and combine, then shared experts."""
     g.matmul(M, d, n_experts, count=count)
@@ -220,7 +224,7 @@ def _moe(g: Graph, M, d, expert_ff, n_experts, top_k, n_shared, tp, count):
     g.matmul(m_eff, expert_ff, d, count=count)
     g.op(P2P, comm=payload, count=count)
     if n_shared:
-        _ffn(g, M, d, expert_ff * n_shared, tp, True, count)
+        ffn(g, M, d, expert_ff * n_shared, tp, True, count)
 
 
 def _mamba(g: Graph, batch, q_len, d, d_state, tp, count, decode):
@@ -252,17 +256,41 @@ def _rwkv(g: Graph, batch, q_len, d, d_ff, tp, count):
     g.allreduce(M * d, count=count)
 
 
+#: the families restated here: the transformer ones (``dense``, ``vlm``,
+#: ``audio`` with its encoder where ``enc_layers`` is set, ``moe``), the
+#: hybrid attention-Mamba-MoE stack, and the RWKV ``ssm``
+OWN_FAMILIES = frozenset({"dense", "vlm", "audio", "moe", "hybrid", "ssm"})
+
+
 def arch_graph(a: Dict, batch: int, seq: int, tp: int, decode: bool,
                kv_len: int) -> Graph:
     """Operator graph of a whole model (every layer, by multiplicity) for
-    the prefill of ``seq`` tokens or one decode step at ``kv_len``."""
+    the prefill of ``seq`` tokens or one decode step at ``kv_len``.
+
+    Every family shares the embedding copy and the logits matmul; the
+    layers between them are restated here for ``OWN_FAMILIES``, and by
+    ``layers(g, a, batch, q_len, kv_len, tp, decode)`` of the family's own
+    file (``spec.family``) for any other."""
     q_len = 1 if decode else seq
+    d = a["d_model"]
+    M = batch * q_len
+    g = Graph(tp)
+    g.memcpy(M * d * BYTES)                                     # embedding
+    if a["family"] in OWN_FAMILIES:
+        _own_layers(g, a, batch, q_len, kv_len, tp, decode)
+    else:
+        spec.family(a["family"]).layers(g, a, batch, q_len, kv_len, tp,
+                                        decode)
+    g.matmul(M, d, a["vocab"] // tp)                            # logits
+    return g
+
+
+def _own_layers(g: Graph, a: Dict, batch: int, q_len: int, kv_len: int,
+                tp: int, decode: bool) -> None:
     d = a["d_model"]
     M = batch * q_len
     L = a["n_layers"]
     fam = a["family"]
-    g = Graph(tp)
-    g.memcpy(M * d * BYTES)                                     # embedding
     attn = (a["n_heads"], a["n_kv_heads"], a["head_dim"])
     if fam == "ssm":
         g.vector(2 * M * d * L / L, 8.0, count=L)
@@ -271,31 +299,29 @@ def arch_graph(a: Dict, batch: int, seq: int, tp: int, decode: bool,
         n_attn = L // a["attn_every"]
         n_moe = L // 2
         g.vector(2 * M * d, 8.0, count=L)
-        _attention(g, batch, q_len, kv_len, d, *attn, tp, n_attn, decode)
+        attention(g, batch, q_len, kv_len, d, *attn, tp, n_attn, decode)
         _mamba(g, batch, q_len, d, a["d_state"], tp, L - n_attn, decode)
-        _moe(g, M, d, a["expert_ff"], a["n_experts"], a["top_k"],
-             a.get("n_shared_experts", 0), tp, n_moe)
-        _ffn(g, M, d, a["d_ff"], tp, True, L - n_moe)
+        moe(g, M, d, a["expert_ff"], a["n_experts"], a["top_k"],
+            a.get("n_shared_experts", 0), tp, n_moe)
+        ffn(g, M, d, a["d_ff"], tp, True, L - n_moe)
     else:
         enc = a.get("enc_layers", 0)
         if enc and not decode:
             ctx = a["enc_ctx"]
-            _attention(g, batch, ctx, ctx, d, *attn, tp, enc, False)
-            _ffn(g, batch * ctx, d, a["d_ff"], tp, False, enc)
+            attention(g, batch, ctx, ctx, d, *attn, tp, enc, False)
+            ffn(g, batch * ctx, d, a["d_ff"], tp, False, enc)
         g.vector(2 * M * d, 8.0, count=L)
-        _attention(g, batch, q_len, kv_len, d, *attn, tp, L, decode)
+        attention(g, batch, q_len, kv_len, d, *attn, tp, L, decode)
         if enc:
-            _attention(g, batch, q_len, a["enc_ctx"], d, *attn, tp, L,
-                       decode)
+            attention(g, batch, q_len, a["enc_ctx"], d, *attn, tp, L,
+                      decode)
         if fam == "moe":
-            _moe(g, M, d, a["expert_ff"], a["n_experts"], a["top_k"],
-                 a.get("n_shared_experts", 0), tp, L)
+            moe(g, M, d, a["expert_ff"], a["n_experts"], a["top_k"],
+                a.get("n_shared_experts", 0), tp, L)
             if a.get("dense_residual", False):
-                _ffn(g, M, d, a["d_ff"], tp, True, L)
+                ffn(g, M, d, a["d_ff"], tp, True, L)
         else:
-            _ffn(g, M, d, a["d_ff"], tp, a.get("gated_mlp", True), L)
-    g.matmul(M, d, a["vocab"] // tp)                            # logits
-    return g
+            ffn(g, M, d, a["d_ff"], tp, a.get("gated_mlp", True), L)
 
 
 def scenarios(cfg: Dict) -> List[Tuple[str, Graph, Graph]]:

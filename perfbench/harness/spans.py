@@ -6,15 +6,19 @@ tracer (``sweep.*``, ``eval.*``, ``dse.*``) and names the phases of its
 jitted sweep step with ``jax.named_scope`` (``sweep.decode``,
 ``sweep.op_terms``, ``sweep.reduce``), which XLA keeps as each
 instruction's op-name metadata; the trace file holds it in the HLO of each
-program it saw run (``harness.xplane``).  This module reduces both:
+program it saw run (``harness.xplane``).  Spans and scopes are found by
+the one rule of ``trace.is_name`` (a lowercase ``family.name``), so a span
+or scope that a later program opens is reduced with no edit here.  This
+module reduces both:
 
-- ``reduce_spans``: for each host annotation inside the window whose name
-  starts with a listed prefix, its count, seconds, self seconds (time not
-  covered by a listed annotation nested in it on the same thread line) and
-  the sum of each integer argument;
-- ``reduce_scopes``: device busy seconds per ``sweep.*`` scope on the
-  busiest chip; an operation whose op name holds no scope counts under
-  ``unscoped``, a fusion under its root's scope;
+- ``reduce_spans``: for each host annotation inside the window that the
+  rule names, its count, seconds, self seconds (time not covered by such an
+  annotation nested in it on the same thread line) and the sum of each
+  integer argument;
+- ``reduce_scopes``: device busy seconds per scope on the busiest chip; an
+  operation counts under every scope of its op-name path (a scope's time
+  holds the scopes nested in it), under ``unscoped`` where the path holds
+  none, and a fusion under its root's path;
 - ``add``: both, as the keys ``spans`` and ``scopes`` of a
   ``trace.reduce_profile`` result (what the span metrics under
   ``metrics/`` read).
@@ -31,10 +35,6 @@ from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
 from harness import trace, xplane
 
-#: the annotations a traced run reduces: the harness's and the program's
-PREFIXES = ("pb.", "sweep.", "eval.", "dse.")
-#: a scope that ``jax.named_scope`` wrote into an op-name path
-SCOPE = re.compile(r"(?:^|/)(sweep\.[A-Za-z_]+)(?=/|$)")
 UNSCOPED = "unscoped"
 #: a TPU operation's event name starts with its HLO instruction
 INSTR = re.compile(r"%?([\w.\-]+)")
@@ -46,10 +46,10 @@ def _stats(ev) -> List[Tuple[str, object]]:
     return [(k, v) for k, v in ev.stats]
 
 
-def reduce_spans(pd, window: trace.Interval,
-                 prefixes: Tuple[str, ...]) -> Dict[str, Dict]:
+def reduce_spans(pd, window: trace.Interval) -> Dict[str, Dict]:
     """``{name: {"count", "s", "self_s", "stats": {arg: sum}}}`` over the
-    listed host annotations that lie inside ``window``."""
+    host annotations that ``trace.is_name`` names and that lie inside
+    ``window``."""
     w0, w1 = window
     out: Dict[str, Dict] = {}
     for plane in pd.planes:
@@ -58,7 +58,7 @@ def reduce_spans(pd, window: trace.Interval,
         for ln in plane.lines:
             evs = []
             for ev in ln.events:
-                if not ev.name.startswith(prefixes):
+                if not trace.is_name(ev.name):
                     continue
                 s = int(ev.start_ns)
                 e = s + int(ev.duration_ns)
@@ -92,9 +92,10 @@ def _close(out: Dict[str, Dict], frame: List) -> None:
     out[frame[1]]["self_s"] -= frame[2] / 1e9
 
 
-def _scope(op_name: str) -> str:
-    m = SCOPE.search(op_name)
-    return m.group(1) if m else UNSCOPED
+def _scopes(op_name: str) -> List[str]:
+    """The scopes of an op-name path, outermost first, or ``unscoped``."""
+    return ([c for c in op_name.split("/") if trace.is_name(c)]
+            or [UNSCOPED])
 
 
 def _module_spans(ln_modules) -> Tuple[List[int], List[Tuple[int, str]]]:
@@ -141,7 +142,8 @@ def reduce_scopes(pd, window: trace.Interval,
                     if i >= 0 and ends[i][0] >= e:
                         module = ends[i][1]
                 op = op_names.get(module, {}).get(instr, "")
-                scopes[_scope(op)].append((max(s, w0), min(e, w1)))
+                for sc in _scopes(op):
+                    scopes[sc].append((max(s, w0), min(e, w1)))
     if not per_dev:
         return {}
     busiest = max(per_dev, key=lambda d: trace.union_length(
@@ -151,12 +153,11 @@ def reduce_scopes(pd, window: trace.Interval,
 
 
 def add(red: Dict, pd, window: trace.Interval, trace_dir: str,
-        prefixes: Tuple[str, ...] = PREFIXES,
         op_lines: Callable = trace.tpu_op_lines) -> Dict:
     """Adds ``spans`` and ``scopes`` to ``red``, a ``trace.reduce_profile``
     result of the trace under ``trace_dir`` over the same window, and
     returns it."""
-    red["spans"] = reduce_spans(pd, window, prefixes)
+    red["spans"] = reduce_spans(pd, window)
     red["scopes"] = reduce_scopes(pd, window, xplane.load_op_names(trace_dir),
                                   op_lines)
     return red

@@ -2,9 +2,11 @@
 
 A configuration is ``configs/<name>.json``, a traffic mix is
 ``traffic/<name>.json`` (data that the unit module named by its ``kind``
-reads: ``units/<kind>.py``), and a metric is ``metrics/<name>.py``, a
-reader with ``read(records) -> float | None``.  Adding any of them is
-adding a file and an entry; no file that exists needs an edit.
+reads: ``units/<kind>.py``), a metric is ``metrics/<name>.py``, a reader
+with ``read(records) -> float | None``, and an architecture family that
+the plain reference does not restate itself is
+``harness/families/<family>.py`` (``-`` read as ``_``).  Adding any of
+them is adding a file and an entry; no file that exists needs an edit.
 """
 from __future__ import annotations
 
@@ -59,6 +61,17 @@ def unit(kind: str, bench_dir: str = BENCH_DIR):
 def metric_reader(name: str, bench_dir: str = BENCH_DIR):
     return _module(os.path.join(bench_dir, "metrics", f"{name}.py"),
                    "perfbench_metric_" + name.replace(".", "_"))
+
+
+def family(name: str, bench_dir: str = BENCH_DIR):
+    """The file that restates an architecture family's layers for the
+    plain reference; an error naming the path where there is none."""
+    stem = name.replace("-", "_")
+    path = os.path.join(bench_dir, "harness", "families", f"{stem}.py")
+    if not os.path.exists(path):
+        raise FileNotFoundError(
+            f"the reference does not restate family {name!r}: no {path}")
+    return _module(path, f"perfbench_family_{stem}")
 
 
 def _applies(metric: Dict, cell_name: str, e2e_names: List[str]) -> bool:

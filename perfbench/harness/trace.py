@@ -4,19 +4,38 @@ breakdown.
 Busy time is the union of the intervals in which an operation ran on a
 device; the idle share is one minus busy over the traced window.  Idle gaps
 are labelled with the innermost host annotation (``jax.profiler.
-TraceAnnotation``) open at the gap's midpoint, which puts the harness's own
-spans and the device on one clock.
+TraceAnnotation``) open at the gap's midpoint, which puts the harness's and
+the program's spans and the device on one clock.
+
+The annotations reduced are those named by one rule (``is_name``): a
+lowercase ``family.name``, such as the harness's ``pb.window`` or the
+program's ``sweep.chunk`` and ``eval.fetch``.  A span that a later program
+opens is reduced with no edit here; JAX's own host events (``$file.py:N
+function`` from the Python tracer, ``PjitFunction(...)``, ``fusion.3``) do
+not follow it.
 """
 from __future__ import annotations
 
 import glob
 import os
+import re
 from collections import defaultdict
 from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
 Interval = Tuple[int, int]       # (start_ns, end_ns)
+
+#: the names of the harness's and the program's annotations and device
+#: scopes: lowercase dotted words, each starting with a letter
+NAME = re.compile(r"[a-z][a-z0-9_]*(?:\.[a-z][a-z0-9_]*)+")
+#: the label of an idle gap in which no such annotation is open
+NO_ANNOTATION = "no annotation open"
+
+
+def is_name(s: str) -> bool:
+    """Whether ``s`` names a span or scope of the harness or the program."""
+    return NAME.fullmatch(s) is not None
 
 
 def tpu_op_lines(plane) -> list:
@@ -57,12 +76,11 @@ def load(path: str):
     return ProfileData.from_file(max(files, key=os.path.getmtime))
 
 
-def reduce_profile(pd, window: Interval, annotations: Tuple[str, ...],
+def reduce_profile(pd, window: Interval,
                    op_lines: Callable = tpu_op_lines) -> Dict:
     """Busy time per device inside ``window`` (ns, on the trace's clock),
     the device operations that took the most time, and idle gaps by the
-    innermost host annotation (names starting with one of
-    ``annotations``) open in each gap."""
+    innermost host annotation (``is_name``) open in each gap."""
     w0, w1 = window
     devices: Dict[str, List[Interval]] = {}
     op_time: Dict[str, float] = defaultdict(float)
@@ -84,7 +102,7 @@ def reduce_profile(pd, window: Interval, annotations: Tuple[str, ...],
         if plane.name.startswith("/host:"):
             for ln in plane.lines:
                 for ev in ln.events:
-                    if ev.name.startswith(annotations):
+                    if is_name(ev.name):
                         s = int(ev.start_ns)
                         host.append((s, s + int(ev.duration_ns), ev.name))
     busy = {}
@@ -113,7 +131,7 @@ def _label(host, hs, t: int) -> str:
     for s, e, name in reversed(host[max(0, i - 512):i]):
         if e >= t:
             return name
-    return "no annotation open"
+    return NO_ANNOTATION
 
 
 def window_from_annotation(pd, name: str) -> Interval:

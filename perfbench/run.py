@@ -8,10 +8,13 @@ in ``BENCHMARK.json``.  A run sets up (imports, device, evaluator, compile
 or compile-cache load, one warm unit), measures for ``--seconds`` with
 nothing compiling, then checks what the window produced against the plain
 reference.  ``--trace 0`` reports the cell's end-to-end metrics;
-``--trace 1`` runs the window under the profiler and reports its per-layer
-metrics.  No TPU, or fewer chips than the cell asks for, exits 2 with no
-result.  The numbers compared with their limits end standard error and the
-result line (under ``checks``).
+``--trace 1`` compiles with the persistent cache off, runs the window under
+the profiler and reports its per-layer metrics, read from the trace's
+device time and from the harness's and the program's spans and device
+scopes (``harness.spans``).  No TPU, or fewer
+chips than the cell asks for, exits 2 with no result.  The numbers compared
+with their limits end standard error and the result line (under
+``checks``).
 """
 import time
 
@@ -57,8 +60,13 @@ class CompileCounter:
 
 
 def measure(workload: str, seed: int, seconds: float, traced: bool,
-            bench=None) -> dict:
-    """Set up, measure and check one run of a cell; returns the result."""
+            bench=None, op_lines=None) -> dict:
+    """Set up, measure and check one run of a cell; returns the result.
+
+    A traced run's result also holds the trace's reduction under
+    ``trace`` (``main`` leaves it out of the line); ``op_lines`` picks
+    the device's op lines in the trace (``harness.trace.tpu_op_lines``
+    by default)."""
     import jax
     bench = bench or spec.load_benchmark()
     cell = spec.cell(bench, workload)
@@ -68,14 +76,22 @@ def measure(workload: str, seed: int, seconds: float, traced: bool,
     enable_compile_cache()
     # cache every program, however fast it compiles, so set-up is steady
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    dev = device.require(cell["chips"])
-    unit = spec.unit(mix["kind"])
-    state = unit.setup(cfg, mix, seed)
-    counter = CompileCounter()
-    setup_s = time.perf_counter() - T_START
-    trace_dir = tempfile.mkdtemp(prefix="perfbench-trace-") if traced else None
-    counter.active = True
+    cache = jax.config.jax_enable_compilation_cache
+    if traced:
+        # a program loaded from the persistent cache brings no op names
+        # into the profiler's trace (seen on a v5e), and the device scopes
+        # are read from them: a traced run compiles its own programs
+        jax.config.update("jax_enable_compilation_cache", False)
+    trace_dir = None
     try:
+        dev = device.require(cell["chips"])
+        unit = spec.unit(mix["kind"])
+        state = unit.setup(cfg, mix, seed)
+        counter = CompileCounter()
+        setup_s = time.perf_counter() - T_START
+        if traced:
+            trace_dir = tempfile.mkdtemp(prefix="perfbench-trace-")
+        counter.active = True
         with (jax.profiler.trace(trace_dir) if traced
               else contextlib.nullcontext()):
             with _annotate(WINDOW):
@@ -85,10 +101,12 @@ def measure(workload: str, seed: int, seconds: float, traced: bool,
         rec = {"setup_s": setup_s, "window": window}
         breakdown = None
         if traced:
-            from harness import trace
+            from harness import spans, trace
             pd = trace.load(trace_dir)
-            red = trace.reduce_profile(
-                pd, trace.window_from_annotation(pd, WINDOW), ("pb.",))
+            win = trace.window_from_annotation(pd, WINDOW)
+            lines = op_lines or trace.tpu_op_lines
+            red = spans.add(trace.reduce_profile(pd, win, op_lines=lines),
+                            pd, win, trace_dir, lines)
             rec["trace"] = red
             used = sorted(red["busy_s"])[:cell["chips"]]
             dev["busy_s"] = sum(red["busy_s"][d] for d in used) / len(used)
@@ -96,6 +114,7 @@ def measure(workload: str, seed: int, seconds: float, traced: bool,
             breakdown = {"device_ops": red["device_ops"],
                          "idle_gaps": red["idle_gaps"]}
     finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
     t_check = time.perf_counter()
@@ -117,6 +136,7 @@ def measure(workload: str, seed: int, seconds: float, traced: bool,
            "compiles_in_window": counter.count, "timing": rec["timing"]}
     if breakdown is not None:
         out["breakdown"] = breakdown
+        out["trace"] = rec["trace"]
     out["checks"] = checks
     return out
 
@@ -139,6 +159,7 @@ def main(argv=None) -> int:
           f"device_count {d['count']}")
     print(f"compiles_in_window {out['compiles_in_window']}")
     t = out.pop("timing")
+    out.pop("trace", None)
     u = sorted(t["unit_s"])
     print(f"timing setup_s {t['setup_s']:.3f} window_s {t['window_s']:.3f} "
           f"check_s {t['check_s']:.3f} units {len(u)} unit_s first "

@@ -3,14 +3,20 @@
     JAX_PLATFORMS=cpu python -m pytest -q perfbench/tests
 
 They cover the trace reduction, the rate and percentile arithmetic,
-discovery of configurations, mixes and metrics by name, every cell's run at
-a tiny size, the control (the reference in bfloat16, which must read as not
-correct), and faults planted under the timed path (each must read as not
-correct).  The look for a chip is skipped by replacing it in the test.
+discovery of configurations, mixes, metrics and architecture families by
+name (a whole run of a cell made of new files only), the reference's
+operator tables, every cell's run at a tiny size, the control (the
+reference in bfloat16, which must read as not correct), and faults planted
+under the timed path (each must read as not correct).  The look for a chip
+is skipped by replacing it in the test.
 """
+import ast
+import glob
+import hashlib
 import json
 import os
 import shutil
+import subprocess
 import sys
 import time
 import types
@@ -30,6 +36,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import run  # noqa: E402
 from harness import device, spec, trace  # noqa: E402
+from harness import reference as R  # noqa: E402
 
 STOP = 1 << 16          # ids per sweep in these tests
 
@@ -79,7 +86,7 @@ def test_trace_reduction_on_a_recorded_cpu_trace(tmp_path):
                     time.sleep(0.02)
     pd = trace.load(str(tmp_path))
     win = trace.window_from_annotation(pd, "pb.window")
-    red = trace.reduce_profile(pd, win, ("pb.",), op_lines=trace.cpu_op_lines)
+    red = trace.reduce_profile(pd, win, op_lines=trace.cpu_op_lines)
     assert red["window_s"] >= 0.08
     (busy,) = red["busy_s"].values()
     assert 0 < busy < red["window_s"]
@@ -146,39 +153,193 @@ def test_cells_metrics_and_files_are_found_by_name():
                 assert callable(spec.metric_reader(m["name"]).read)
 
 
-def test_a_new_cell_is_picked_up_without_editing_a_file(tmp_path):
+#: a family the reference does not restate itself, restated by its own
+#: file: the dense transformer layers, as the program builds them
+FAMILY_FILE = """from harness.reference import attention, ffn
+
+
+def layers(g, a, batch, q_len, kv_len, tp, decode):
+    d, L = a["d_model"], a["n_layers"]
+    M = batch * q_len
+    g.vector(2 * M * d, 8.0, count=L)
+    attention(g, batch, q_len, kv_len, d, a["n_heads"], a["n_kv_heads"],
+              a["head_dim"], tp, L, decode)
+    ffn(g, M, d, a["d_ff"], tp, a["gated_mlp"], L)
+"""
+#: the same file with one layer's op (the norms) left out
+FAMILY_FILE_MISSING_OP = FAMILY_FILE.replace(
+    "    g.vector(2 * M * d, 8.0, count=L)\n", "")
+
+#: ``run.measure`` of a copied checkout in a child process: no look for a
+#: chip, tiny sweeps, the CPU's op lines, and a program that opens a span
+#: no harness file lists around each sweep
+CHILD = """import json, sys
+sys.path[:0] = [sys.argv[1], sys.argv[2]]
+import run
+from harness import device, spec, trace
+from repro.obs import NOOP
+from repro.perfmodel.sweep import SweepEngine
+device.require = lambda chips: {"platform": "cpu", "kind": "cpu", "count": 1}
+device.memory_peak = lambda chips: 0
+real_unit = spec.unit
+
+
+def unit(kind, *a):
+    u = real_unit(kind, *a)
+    u.STOP = %d
+    return u
+
+
+def sweep_run(self, *a, real=SweepEngine.run, **k):
+    with NOOP.span("probe.sweep", calls=1):
+        return real(self, *a, **k)
+
+
+spec.unit, SweepEngine.run = unit, sweep_run
+out = run.measure("new-cell", 2 ** 31 + 5, 0.3, True,
+                  op_lines=trace.cpu_op_lines)
+out.pop("trace")
+print(json.dumps(out))
+""" % STOP
+
+
+def _new_checkout(tmp_path, family_file):
+    """A checkout of the benchmark plus only new files and new entries: a
+    configuration whose arch has a family the reference does not restate
+    itself, that family's file, a mix, a cell, and per-layer metrics (one
+    of them reading a program span no harness file lists)."""
     root = tmp_path / "checkout"
     shutil.copytree(BENCH, root / "perfbench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     bench = json.loads(json.dumps(BENCH_JSON))
     bd = root / "perfbench"
-    (bd / "configs" / "gpt3-small-batch.json").write_text(json.dumps(
-        dict(spec.config(BENCH_JSON, "gpt3-pair"), name="gpt3-small-batch")))
+    zoo = spec.config(BENCH_JSON, "zoo9-portfolio")
+    archs = {nm: dict(zoo["archs"][nm]) for nm in ("codeqwen1.5-7b",
+                                                    "internvl2-2b")}
+    archs["codeqwen1.5-7b"]["family"] = "dense-restated"
+    (bd / "configs" / "codeqwen-restated.json").write_text(json.dumps(
+        dict(zoo, name="codeqwen-restated", reduced=[], archs=archs)))
+    (bd / "harness" / "families" / "dense_restated.py").write_text(
+        family_file)
     (bd / "traffic" / "sweep-stall2.json").write_text(json.dumps(
-        dict(spec.traffic("sweep-stall8"), stall_topk=2)))
+        dict(spec.traffic("sweep-stall4"), stall_topk=2)))
     (bd / "metrics" / "sweeps_in_window.py").write_text(
         "def read(rec):\n    return rec['window']['units']\n")
-    bench["configs"].append({"name": "gpt3-small-batch", "source": "x",
-                             "file": "perfbench/configs/gpt3-small-batch.json",
+    (bd / "metrics" / "probed_sweeps.py").write_text(
+        "from harness.spans import program_spans\n\n\n"
+        "def read(rec):\n"
+        "    sp = program_spans(rec, 'sweep', 'probe.sweep')\n"
+        "    return None if sp is None else sp['probe.sweep']['count']\n")
+    bench["configs"].append({"name": "codeqwen-restated", "source": "x",
+                             "file": "perfbench/configs/"
+                                     "codeqwen-restated.json",
                              "reduced": [], "why": "x"})
     bench["workloads"].append({"name": "new-cell",
-                               "config": "gpt3-small-batch",
+                               "config": "codeqwen-restated",
                                "traffic": "sweep-stall2", "chips": 1,
                                "why": "x"})
-    bench["per_layer"].append({"name": "sweeps_in_window", "unit": "n",
-                               "better": "higher", "source": "host_clock",
-                               "layer": "sweep host loop",
-                               "moves": "sweep_designs_per_s",
-                               "workloads": ["new-cell"]})
+    for name in ("sweeps_in_window", "probed_sweeps"):
+        bench["per_layer"].append({"name": name, "unit": "n",
+                                   "better": "higher",
+                                   "source": "program_span",
+                                   "layer": "sweep host loop",
+                                   "moves": "sweep_designs_per_s",
+                                   "workloads": ["new-cell"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return root
+
+
+def _measure_in_checkout(root) -> dict:
+    proc = subprocess.run(
+        [sys.executable, "-c", CHILD, str(root / "perfbench"),
+         os.path.join(ROOT, "src")],
+        capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_a_new_cell_is_picked_up_without_editing_a_file(tmp_path):
+    root = _new_checkout(tmp_path, FAMILY_FILE)
+    bd = root / "perfbench"
     b = spec.load_benchmark(str(root))
     c = spec.cell(b, "new-cell")
-    assert spec.config(b, c["config"], str(root))["name"] == "gpt3-small-batch"
+    assert spec.config(b, c["config"], str(root))["name"] == \
+        "codeqwen-restated"
     assert spec.traffic(c["traffic"], str(bd))["stall_topk"] == 2
     names = [m["name"] for m in spec.cell_metrics(b, "new-cell", True)]
-    assert "sweeps_in_window" in names
+    assert {"sweeps_in_window", "probed_sweeps"} <= set(names)
     reader = spec.metric_reader("sweeps_in_window", str(bd))
     assert reader.read({"window": {"units": 7}}) == 7
+    assert spec.family("dense-restated", str(bd)).layers
+    out = _measure_in_checkout(root)
+    assert out["correct"], out["checks"]
+    assert set(out["metrics"]) == set(names)
+    assert out["metrics"]["probed_sweeps"]["value"] == out["attempted"]
+
+
+def test_a_family_file_that_leaves_out_an_op_reads_as_not_correct(
+        tmp_path):
+    out = _measure_in_checkout(_new_checkout(tmp_path,
+                                             FAMILY_FILE_MISSING_OP))
+    assert not out["correct"]
+    assert out["checks"]["objective_rel_err"]["value"] > \
+        out["checks"]["objective_rel_err"]["limit"]
+
+
+def test_an_unknown_family_with_no_file_raises():
+    a = dict(spec.config(BENCH_JSON, "zoo9-portfolio")["archs"]
+             ["codeqwen1.5-7b"], family="no-such-family")
+    with pytest.raises(FileNotFoundError, match=r"families/no_such_family"):
+        R.arch_graph(a, 8, 2048, 8, False, 2048)
+
+
+#: sha256 of every scenario's prefill and decode operator tables, taken on
+#: the tree before family files existed: the reference's graphs of the
+#: configurations it restates itself must stay byte-identical
+TABLE_SHA256 = {
+    "gpt3-pair":
+        "cae0c95fd1cd1302bf9ad7a0ec0d2606e8f256f18fc727e141aca620de909d24",
+    "zoo9-portfolio":
+        "d288ce096bba1dc15f56c41e6704582d80ffd7d7749529ab2c5a2357dbd99fa0",
+}
+
+
+@pytest.mark.parametrize("config", sorted(TABLE_SHA256))
+def test_reference_operator_tables_are_unchanged(config):
+    h = hashlib.sha256()
+    for name, pre, dec in R.scenarios(spec.config(BENCH_JSON, config)):
+        h.update(name.encode())
+        for g in (pre, dec):
+            t = g.table()
+            for k in R.Graph.FIELDS:
+                h.update(k.encode())
+                h.update(t[k].tobytes())
+    assert h.hexdigest() == TABLE_SHA256[config]
+
+
+def _imports_the_program(source: str) -> bool:
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, ast.Import):
+            mods = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            mods = [node.module or ""]
+        else:
+            continue
+        if any(m == "repro" or m.startswith("repro.") for m in mods):
+            return True
+    return False
+
+
+def test_family_files_import_nothing_of_the_program():
+    files = glob.glob(os.path.join(BENCH, "harness", "families", "*.py"))
+    assert files
+    for f in files:
+        with open(f) as fh:
+            assert not _imports_the_program(fh.read()), f
+    assert not _imports_the_program(FAMILY_FILE)
+    assert _imports_the_program("from repro.perfmodel import workload\n")
+    assert _imports_the_program("import repro.configs as c\n")
 
 
 def test_no_tpu_exits_nonzero_with_no_result(capsys):
@@ -201,11 +362,10 @@ def test_cell_runs_and_is_correct_at_a_tiny_size(cpu_run, cell):
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_traced_run_reports_per_layer_metrics(cpu_run, cell, monkeypatch):
-    real = trace.reduce_profile
-    monkeypatch.setattr(trace, "reduce_profile", lambda pd, w, a, **k: real(
-        pd, w, a, op_lines=trace.cpu_op_lines))
-    out = run.measure(cell, 7, 0.3, True)
+def test_traced_run_reports_per_layer_metrics(cpu_run, cell):
+    cache = jax.config.jax_enable_compilation_cache
+    out = run.measure(cell, 7, 0.3, True, op_lines=trace.cpu_op_lines)
+    assert jax.config.jax_enable_compilation_cache == cache
     assert out["correct"], out["checks"]
     want = {m["name"] for m in spec.cell_metrics(BENCH_JSON, cell, True)}
     assert set(out["metrics"]) == want
