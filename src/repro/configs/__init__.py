@@ -20,12 +20,13 @@ from repro.configs.jamba15_large_398b import CONFIG as jamba15_large_398b
 from repro.configs.internvl2_2b import CONFIG as internvl2_2b
 from repro.configs.whisper_medium import CONFIG as whisper_medium
 from repro.configs.rwkv6_7b import CONFIG as rwkv6_7b
+from repro.configs.deepseek_v3 import CONFIG as deepseek_v3
 
 ARCHS: Dict[str, ArchConfig] = {
     c.name: c for c in (
         codeqwen15_7b, mistral_nemo_12b, qwen25_14b, llama32_1b,
         qwen2_moe_a27b, arctic_480b, jamba15_large_398b, internvl2_2b,
-        whisper_medium, rwkv6_7b,
+        whisper_medium, rwkv6_7b, deepseek_v3,
     )
 }
 

@@ -8,7 +8,7 @@ from typing import Optional, Tuple
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                 # dense | moe | hybrid | vlm | audio | ssm
+    family: str                 # dense | moe | hybrid | vlm | audio | ssm | mla_moe
     n_layers: int
     d_model: int
     n_heads: int
@@ -33,6 +33,25 @@ class ArchConfig:
     # (perf iteration, EXPERIMENTS.md §Perf: EP beats intra-expert TP for
     # the dispatch collectives; the router never selects a padding expert)
     expert_pad: int = 0
+
+    # multi-head latent attention (DeepSeek-V3, family mla_moe): queries
+    # through a q_lora_rank latent, keys and values through one cached
+    # kv_lora_rank latent plus a qk_rope_head_dim RoPE key shared by all
+    # heads; q/k heads are qk_nope_head_dim + qk_rope_head_dim wide
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading layers with a dense FFN (width d_ff) before the MoE layers
+    first_k_dense: int = 0
+    # group-limited routing (DeepSeek-V3): sigmoid scores, the top
+    # `topk_group` of `n_group` expert groups (ranked by the sum of each
+    # group's top-2 scores), top_k experts among them, weights normalized
+    # and scaled by `routed_scaling`; 0 groups keeps softmax top-k routing
+    n_group: int = 0
+    topk_group: int = 0
+    routed_scaling: float = 1.0
 
     # hybrid (Jamba): one attention layer per `attn_every`; MoE every 2nd layer
     attn_every: int = 0
@@ -69,7 +88,8 @@ class ArchConfig:
             head_dim=16,
             d_ff=128,
             vocab=256,
-            n_experts=min(self.n_experts, 4) if self.n_experts else 0,
+            n_experts=(min(self.n_experts, 8 if self.n_group else 4)
+                       if self.n_experts else 0),
             top_k=min(self.top_k, 2) if self.top_k else 0,
             n_shared_experts=min(self.n_shared_experts, 1),
             expert_ff=64 if self.expert_ff else 0,
@@ -77,6 +97,14 @@ class ArchConfig:
             enc_ctx=min(self.enc_ctx, 16) if self.enc_ctx else 0,
             d_state=min(self.d_state, 8),
             rwkv_head_size=16,
+            q_lora_rank=min(self.q_lora_rank, 32),
+            kv_lora_rank=min(self.kv_lora_rank, 16),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 8),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 8),
+            v_head_dim=min(self.v_head_dim, 8),
+            first_k_dense=min(self.first_k_dense, 1),
+            n_group=min(self.n_group, 4),
+            topk_group=min(self.topk_group, 2),
         )
 
 

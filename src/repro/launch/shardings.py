@@ -9,7 +9,9 @@ Strategy (see DESIGN.md §5):
   * SP (sequence sharding) for long_500k KV caches, for GQA caches whose
     kv-head count doesn't divide the model axis (flash-decode layout), and,
     via activation constraints, for residual streams (Megatron sequence
-    parallelism).
+    parallelism);
+  * latent attention (MLA): replicated latent down-projections and latent
+    cache, head-split up-projections.
 
 jax requires argument shardings to divide dims exactly, so every rule is
 divisibility-checked against the actual leaf shape and falls back to the
@@ -67,6 +69,13 @@ def _base_spec(keys, shape: Tuple[int, ...], model_size: int) -> Tuple:
         return (None, None, m(1))
     if last == "router":
         return (None, None)
+
+    # latent attention: the latent down-projections are replicated (every
+    # rank needs the whole latent); the up-projections split the heads
+    if has("q_a", "kv_a") and last == "w":
+        return (None, None)
+    if has("q_b", "kv_b") and last == "w":
+        return (None, m(1))
 
     # attention / rwkv / mamba linears
     if has("q", "k", "v", "g", "r", "w_proj", "cm_k", "in_proj") and last == "w":
@@ -156,7 +165,8 @@ def cache_spec(cfg: ArchConfig, shape: ShapeConfig, dp, dp_size: int,
     KV layout decision tree:
       * kv-heads divide the model axis -> shard heads (classic TP serving);
       * else -> shard the KV sequence over 'model' (flash-decode layout);
-      * batch==1 (long_500k) -> the data axes also land on the sequence dim.
+      * batch==1 (long_500k) -> the data axes also land on the sequence dim;
+      * latent attention caches one latent for all heads -> no 'model' dim.
     """
     dp = tuple(dp)
     seq_sharded = shape.global_batch == 1
@@ -172,6 +182,10 @@ def cache_spec(cfg: ArchConfig, shape: ShapeConfig, dp, dp_size: int,
     h_ax = "model" if heads_ok else None
 
     kv = P(None, b_ax, s_ax, h_ax, None)          # (L, B, S, kvH, hd)
+    if cfg.family == "mla_moe":
+        # one latent a token, shared by every head: replicated over 'model'
+        lat = P(None, b_ax, tuple(dp) if seq_sharded else None, None)
+        return {"latent_dense": lat, "latent": lat, "len": P()}
     d_ax = "model" if _div(cfg.d_model, model_size) else None
     if cfg.family == "ssm":
         return {

@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import attention as A
+from repro.models import mla as MLA
 from repro.models import moe as M
 from repro.models import ssm as S
 from repro.models.layers import (init_linear, init_mlp, layer_norm, linear,
@@ -110,6 +111,14 @@ class Model:
             n_blocks = cfg.n_layers // cfg.attn_every
             params["layers"] = _stack_init(k_layers, n_blocks,
                                            self._init_jamba_block)
+        elif fam == "mla_moe":
+            n_dense = self._n_dense
+            if n_dense:
+                params["dense_layers"] = _stack_init(
+                    k_enc, n_dense, lambda k: self._init_mla_layer(k, False))
+            params["layers"] = _stack_init(
+                k_layers, cfg.n_layers - n_dense,
+                lambda k: self._init_mla_layer(k, True))
         elif fam == "audio":
             params["enc_layers"] = _stack_init(k_enc, cfg.enc_layers,
                                                self._init_encoder_layer)
@@ -148,6 +157,27 @@ class Model:
         else:
             p["mlp"] = init_mlp(kf, cfg.d_model, cfg.d_ff,
                                 gated=cfg.gated_mlp, dtype=self.dtype)
+        return p
+
+    @property
+    def _n_dense(self) -> int:
+        """Leading dense layers of an ``mla_moe`` stack."""
+        return min(self.cfg.first_k_dense, self.cfg.n_layers)
+
+    def _init_mla_layer(self, key, moe: bool):
+        cfg = self.cfg
+        ka, kf = jax.random.split(key)
+        p = {"ln1": jnp.ones((cfg.d_model,), jnp.float32),
+             "ln2": jnp.ones((cfg.d_model,), jnp.float32),
+             "attn": MLA.init_mla(ka, cfg, self.dtype)}
+        if moe:
+            p["moe"] = M.init_moe(kf, cfg.d_model, cfg.expert_ff,
+                                  cfg.n_experts, cfg.n_shared_experts,
+                                  cfg.expert_ff * cfg.n_shared_experts,
+                                  self.dtype, router_bias=True)
+        else:
+            p["mlp"] = init_mlp(kf, cfg.d_model, cfg.d_ff, gated=True,
+                                dtype=self.dtype)
         return p
 
     def _init_encoder_layer(self, key):
@@ -208,6 +238,8 @@ class Model:
             x, aux_total = self._rwkv_stack(params, x)
         elif fam == "hybrid":
             x, aux_total = self._jamba_stack(params, x)
+        elif fam == "mla_moe":
+            x, aux_total = self._mla_stack(params, x)
         elif fam == "audio":
             enc = self._encoder_stack(params, batch["frames"].astype(self.dtype))
             x, aux_total = self._decoder_stack(params, x, enc=enc)
@@ -274,6 +306,41 @@ class Model:
         (x, aux), _ = self._scan(self._maybe_remat(body),
                                  (x, jnp.zeros((), jnp.float32)),
                                  params["layers"])
+        return x, aux
+
+    # ---------------- latent-attention (DeepSeek-V3) stack ----------------
+    def _mla_ffn(self, lp, hin):
+        cfg = self.cfg
+        if "moe" not in lp:
+            return mlp(lp["mlp"], hin, gated=True), jnp.zeros((), jnp.float32)
+        return M.deepseek_moe_block(
+            lp["moe"], hin, n_experts=cfg.n_experts, top_k=cfg.top_k,
+            n_group=cfg.n_group, topk_group=cfg.topk_group,
+            routed_scaling=cfg.routed_scaling,
+            capacity_factor=self.moe_capacity, n_groups=self.moe_groups,
+            buf_pspec=self.moe_buf_pspec)
+
+    def _mla_stacks(self):
+        """(layer-param stack, cache entry) of the dense layers, then of
+        the MoE layers."""
+        dense = [("dense_layers", "latent_dense")] if self._n_dense else []
+        return dense + [("layers", "latent")]
+
+    def _mla_stack(self, params, x):
+        cfg = self.cfg
+
+        def body(carry, lp):
+            h, aux = carry
+            h = h + MLA.attention_block(
+                lp["attn"], rms_norm(lp["ln1"], h, cfg.norm_eps), cfg)
+            f, a_loss = self._mla_ffn(lp, rms_norm(lp["ln2"], h,
+                                                   cfg.norm_eps))
+            return (self._constrain(h + f), aux + a_loss), None
+
+        aux = jnp.zeros((), jnp.float32)
+        for name, _ in self._mla_stacks():
+            (x, aux), _ = self._scan(self._maybe_remat(body), (x, aux),
+                                     params[name])
         return x, aux
 
     def _encoder_stack(self, params, frames):
@@ -373,6 +440,14 @@ class Model:
             stacked_m = jax.tree.map(
                 lambda a: jnp.stack([jnp.stack([a] * (cfg.attn_every - 1))] * nb), ms)
             return {**kv(nb), "mamba": stacked_m, "len": jnp.zeros((), jnp.int32)}
+        if fam == "mla_moe":
+            width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+            def lat(n):
+                return jnp.zeros((n, batch_size, max_len, width), self.dtype)
+            return {"latent_dense": lat(self._n_dense),
+                    "latent": lat(cfg.n_layers - self._n_dense),
+                    "len": jnp.zeros((), jnp.int32)}
         cache = {**kv(cfg.n_layers), "len": jnp.zeros((), jnp.int32)}
         if fam == "audio":
             cache["enc"] = enc_out
@@ -388,6 +463,8 @@ class Model:
             x, cache = self._rwkv_decode(params, cache, x)
         elif fam == "hybrid":
             x, cache = self._jamba_decode(params, cache, x)
+        elif fam == "mla_moe":
+            x, cache = self._mla_decode(params, cache, x)
         else:
             x, cache = self._decoder_decode(params, cache, x)
         x = rms_norm(params["final_norm"], x, cfg.norm_eps)
@@ -431,6 +508,24 @@ class Model:
             body, x, (params["layers"], cache["k"], cache["v"]))
         new_cache = dict(cache, k=new_k, v=new_v, len=cache["len"] + 1)
         return h, new_cache
+
+    def _mla_decode(self, params, cache, x):
+        cfg = self.cfg
+        length = cache["len"]
+
+        def body(h, lp_and_cache):
+            lp, lat = lp_and_cache
+            a, lat = MLA.cached_attention_step(
+                lp["attn"], rms_norm(lp["ln1"], h, cfg.norm_eps), lat,
+                length, cfg)
+            h = h + a
+            f, _ = self._mla_ffn(lp, rms_norm(lp["ln2"], h, cfg.norm_eps))
+            return h + f, lat
+
+        new = dict(cache, len=length + 1)
+        for name, key in self._mla_stacks():
+            x, new[key] = self._scan(body, x, (params[name], cache[key]))
+        return x, new
 
     def _rwkv_decode(self, params, cache, x):
         cfg = self.cfg

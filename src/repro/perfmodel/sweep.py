@@ -248,8 +248,10 @@ class SweepEngine:
         Optional :class:`~repro.obs.metrics.MetricsRegistry` and tracer;
         the engine registers a chunk counter and a per-chunk wall time
         histogram, and wraps ``run``, worker spans and each chunk's phases
-        in trace spans (``sweep.chunk`` > ``sweep.filter``, ``sweep.wait``,
-        ``sweep.fetch``, ``sweep.insert`` with ``rows``).  Defaults: a
+        in trace spans (``sweep.run`` with ``op_rows``, the per-op term
+        rows the chunk step evaluates for one design; ``sweep.chunk`` >
+        ``sweep.filter``, ``sweep.wait``, ``sweep.fetch``, ``sweep.insert``
+        with ``rows``).  Defaults: a
         private registry, and the no-op tracer, whose spans still reach a
         ``jax.profiler`` trace.  The jitted chunk step names its phases
         with ``jax.named_scope``: ``sweep.decode``, ``sweep.op_terms``,
@@ -432,6 +434,15 @@ class SweepEngine:
         self._step = jax.jit(
             self._step_portfolio_impl if self._portfolio else self._step_impl,
             donate_argnums=(0,))
+        # per-op term rows the chunk step evaluates for one design: both
+        # workloads' tables on the pair step; the union, plus the stall
+        # columns' second pass, on the portfolio step
+        if self._portfolio:
+            self.op_rows = self._stack.n_unique + (
+                len(self._stall_cols) if self.stall_topk else 0)
+        else:
+            self.op_rows = (len(self.ttft_model.wl.ops)
+                            + len(self.tpot_model.wl.ops))
 
     def _scenario_refs(self) -> np.ndarray:
         """(S, 3) reference [prefill, decode, area] per scenario (A100)."""
@@ -872,7 +883,7 @@ class SweepEngine:
         tr = self.tracer
         t0 = time.perf_counter()
         with tr.span("sweep.run", start=int(start), stop=int(stop),
-                     workers=workers):
+                     workers=workers, op_rows=self.op_rows):
             parent = tr.current_ctx()
             if workers == 1:
                 states = [self._run_span(

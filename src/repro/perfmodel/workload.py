@@ -11,8 +11,9 @@ Builders:
   evaluation workload (single GPT-3 175B layer, TP=8, batch 8, seq 2048,
   FP16; TPOT at output token 1024).
 * :func:`from_arch` — operator graph for any assigned architecture config
-  (dense / MoE / hybrid-SSM / RWKV / enc-dec / VLM backbone), so every arch
-  doubles as a DSE workload.
+  (dense / MoE / hybrid-SSM / RWKV / enc-dec / VLM backbone / latent
+  attention with distinct-expert MoE), so every arch doubles as a DSE
+  workload.
 
 Two anchors here are parsed by :mod:`repro.analysis.influence`: the op-kind
 constants (``MATMUL``/``VECTOR``/...) resolve the roofline guard
@@ -236,6 +237,113 @@ def _moe_block(ops: List[Op], pfx: str, M: float, d: float, expert_ff: float,
                    gated=True, count=count)
 
 
+def distinct_experts(M: float, n_experts: int, top_k: int,
+                     ep: int) -> float:
+    """Expected distinct experts, of the ``n_experts / ep`` one
+    expert-parallel rank holds, that ``M`` tokens hit under uniform
+    routing: each token picks a given expert with probability
+    ``top_k / n_experts``, independently of the other tokens."""
+    return n_experts / ep * (1.0 - (1.0 - top_k / n_experts) ** M)
+
+
+def _mla_block(ops: List[Op], pfx: str, batch: int, q_len: int,
+               kv_len: int, d: float, cfg, tp: int, count: float,
+               decode: bool) -> None:
+    """Multi-head latent attention, heads tensor-parallel over ``tp``.
+
+    The cache holds one latent a token, ``kv_lora_rank + qk_rope_head_dim``
+    values shared by every head, whole on every rank.  Prefill decompresses
+    it (``kv_b``) and attends at q/k width ``nope + rope`` and v width
+    ``v_head_dim``; decode absorbs the up-projections, W_UK into the query
+    and W_UV after the latent-space AV, so both products run over the
+    cached latent with ``m`` = the rank's heads.
+
+    Per layer, ``M = batch * q_len``, ``hl = n_heads / tp``, ``r`` =
+    ``kv_lora_rank``, ``dr`` = ``qk_rope_head_dim``:
+
+    - norms of the q latent (``M * q_lora_rank``) and the kv latent
+      (``M * r``); RoPE's elementwise work is left out, as every family
+      here leaves it out;
+    - ``q_a`` M x d -> q_lora_rank and ``kv_a`` M x d -> r + dr, both
+      replicated (not divided by tp); ``q_b`` q_lora_rank -> hl * (nope +
+      dr);
+    - prefill: ``kv_b`` r -> hl * (nope + v); qk ``(q_len, nope + dr,
+      kv_len)`` and av ``(q_len, kv_len, v)``, each ``batch * hl`` times,
+      with the softmax between; the latent write ``M * (r + dr)`` values;
+    - decode: W_UK absorbed into the query, ``(batch, nope, r)`` ``hl``
+      times; one read of the cache, ``batch * kv_len * (r + dr)`` values;
+      scores ``m = hl, k = r + dr, n = kv_len`` and values ``m = hl, k =
+      kv_len, n = r``, ``batch`` times each, their bytes without the cache
+      (read once by the copy); W_UV ``(batch, r, v)`` ``hl`` times; the
+      latent append;
+    - ``o`` hl * v -> d and the all-reduce of ``M * d``."""
+    hl = max(1, cfg.n_heads // tp)
+    M = batch * q_len
+    r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    lat = r + dr
+    ops.append(_vector(f"{pfx}.q_norm", M * cfg.q_lora_rank, 8.0, count=count))
+    ops.append(_vector(f"{pfx}.kv_norm", M * r, 8.0, count=count))
+    ops.append(_matmul(f"{pfx}.q_a", M, d, cfg.q_lora_rank, count=count))
+    ops.append(_matmul(f"{pfx}.q_b", M, cfg.q_lora_rank, hl * (nope + dr),
+                       count=count))
+    ops.append(_matmul(f"{pfx}.kv_a", M, d, lat, count=count))
+    if decode:
+        ops.append(_matmul(f"{pfx}.absorb_uk", batch, nope, r,
+                           count=count * hl))
+        ops.append(_memcpy(f"{pfx}.latent_read",
+                           batch * kv_len * lat * BYTES, count=count))
+        ops.append(Op(f"{pfx}.scores", MATMUL,
+                      flops=2.0 * hl * lat * kv_len,
+                      bytes=hl * (lat + kv_len) * BYTES,
+                      m=hl, n=kv_len, k=lat, count=count * batch))
+        ops.append(_vector(f"{pfx}.softmax", batch * hl * kv_len, 6.0,
+                           count=count))
+        ops.append(Op(f"{pfx}.values", MATMUL,
+                      flops=2.0 * hl * kv_len * r,
+                      bytes=hl * (kv_len + r) * BYTES,
+                      m=hl, n=r, k=kv_len, count=count * batch))
+        ops.append(_matmul(f"{pfx}.absorb_uv", batch, r, vd,
+                           count=count * hl))
+        ops.append(_memcpy(f"{pfx}.latent_append", batch * lat * BYTES,
+                           count=count))
+    else:
+        ops.append(_matmul(f"{pfx}.kv_b", M, r, hl * (nope + vd),
+                           count=count))
+        ops.append(_matmul(f"{pfx}.qk", q_len, nope + dr, kv_len,
+                           count=count * batch * hl))
+        ops.append(_vector(f"{pfx}.softmax", batch * hl * q_len * kv_len,
+                           6.0, count=count))
+        ops.append(_matmul(f"{pfx}.av", q_len, kv_len, vd,
+                           count=count * batch * hl))
+        ops.append(_memcpy(f"{pfx}.latent_write", M * lat * BYTES,
+                           count=count))
+    ops.append(_matmul(f"{pfx}.o", M, hl * vd, d, count=count))
+    ops.append(_allreduce(f"{pfx}.ar", M * d, count=count))
+
+
+def _distinct_expert_moe_block(ops: List[Op], pfx: str, M: float, d: float,
+                               cfg, tp: int, count: float) -> None:
+    """Expert layer whose experts are expert-parallel over the ``tp`` ranks
+    that hold attention's tokens, every token already on every rank.
+
+    A rank streams the weights of each distinct expert its tokens hit,
+    ``D = distinct_experts(M, ...)`` of its ``n_experts / tp``, and runs
+    them as one grouped GEMM of ``D`` experts with ``M * top_k / (tp * D)``
+    rows each.  The shared expert's all-reduce is the layer's combine: the
+    routed experts add no collective of their own."""
+    E, k, ff = cfg.n_experts, cfg.top_k, cfg.expert_ff
+    D = distinct_experts(M, E, k, tp)
+    m_exp = M * k / (tp * D)
+    ops.append(_matmul(f"{pfx}.router", M, d, E, count=count))
+    ops.append(_vector(f"{pfx}.route_topk", M * E, 4.0, count=count))
+    ops.append(_matmul(f"{pfx}.exp_up", m_exp, d, 2 * ff, count=count * D))
+    ops.append(_vector(f"{pfx}.exp_act", M * k / tp * ff, 8.0, count=count))
+    ops.append(_matmul(f"{pfx}.exp_down", m_exp, ff, d, count=count * D))
+    _ffn_block(ops, f"{pfx}.shared", M, d, ff * cfg.n_shared_experts, tp,
+               gated=True, count=count)
+
+
 def _ssm_block(ops: List[Op], pfx: str, batch: int, q_len: float, d: float,
                d_state: int, tp: int, count: float, decode: bool) -> None:
     """Mamba-style selective-scan block (memory/vector bound)."""
@@ -294,7 +402,18 @@ def from_arch(cfg, batch: int, seq: int, tp: int = 8, decode: bool = False,
     # embeddings / logits (vocab matmul is TP-sharded on vocab)
     ops.append(_memcpy("embed", M * d * BYTES))
 
-    if fam == "ssm":  # rwkv6
+    if fam == "mla_moe":  # deepseek-v3: MLA everywhere, dense then MoE
+        n_dense = min(cfg.first_k_dense, n_layers)
+        ops.append(_vector("ln_all", 2 * M * d, 8.0, count=n_layers))
+        _mla_block(ops, "mla", batch, q_len, kv_len, d, cfg, tp,
+                   count=n_layers, decode=decode)
+        if n_dense:
+            _ffn_block(ops, "ffn", M, d, cfg.d_ff, tp, gated=True,
+                       count=n_dense)
+        if n_layers > n_dense:
+            _distinct_expert_moe_block(ops, "moe", M, d, cfg, tp,
+                                       count=n_layers - n_dense)
+    elif fam == "ssm":  # rwkv6
         ops.append(_vector("ln_all", 2 * M * d * n_layers / n_layers, 8.0, count=n_layers))
         _rwkv_block(ops, "rwkv", batch, q_len, d, cfg.d_ff, tp,
                     count=n_layers, decode=decode)

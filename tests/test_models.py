@@ -50,7 +50,8 @@ def test_smoke_forward_loss_decode(arch):
         or True
 
 
-@pytest.mark.parametrize("arch", ["llama3.2-1b", "rwkv6-7b", "qwen2-moe-a2.7b"])
+@pytest.mark.parametrize("arch", ["llama3.2-1b", "rwkv6-7b", "qwen2-moe-a2.7b",
+                                  "deepseek-v3"])
 def test_decode_matches_teacher_forcing(arch):
     """Step-by-step decode logits == teacher-forced forward logits.
 
@@ -104,7 +105,7 @@ def test_chunked_attention_matches_full():
 
 
 def test_all_archs_have_all_shape_cells():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
     assert len(SHAPES) == 4
     skips = sum(len(a.skip_shapes) for a in ARCHS.values())
-    assert skips == 8                      # 8 full-attention long_500k skips
+    assert skips == 9                      # 9 full-attention long_500k skips

@@ -129,7 +129,7 @@ def test_get_evaluator_zoo_suite():
     assert ev is get_evaluator("proxy", suite="zoo")       # memoized
     assert ev is not get_evaluator("proxy")                # distinct key
     assert ev.stacked
-    assert len(ev.scenarios) == 10                         # every arch config
+    assert len(ev.scenarios) == 11                         # every arch config
     names = {s.name for s in ev.scenarios}
     assert {"arctic-480b", "rwkv6-7b", "whisper-medium"} <= names
     for s in ev.scenarios:

@@ -119,3 +119,23 @@ def test_gqa_cache_fallback():
                        ("data",), 16, MODEL)
     assert nemo["k"][2] in ("model", ("model",)) and nemo["k"][3] is None
     assert cq["k"][3] == "model" and cq["k"][2] is None
+
+
+def test_mla_rules():
+    """DeepSeek-V3's latent attention: the latent down-projections and the
+    latent cache are replicated over 'model' (every rank needs the whole
+    latent), the up-projections split the heads, experts shard by EP."""
+    cfg = ARCHS["deepseek-v3"]
+    params = jax.eval_shape(build_model(cfg).init, jax.random.key(0))
+    spec = SH.param_specs(params, MODEL)
+    attn = spec["layers"]["attn"]
+    assert attn["q_a"]["w"] == P(None, None, None)
+    assert attn["kv_a"]["w"] == P(None, None, None)
+    assert attn["q_b"]["w"] == P(None, None, "model")
+    assert attn["kv_b"]["w"] == P(None, None, "model")
+    assert attn["o"]["w"] == P(None, "model", None)
+    assert spec["layers"]["moe"]["w_up"][1] == "model"
+    assert spec["dense_layers"]["mlp"]["w_up"] == P(None, None, "model")
+    cache = SH.cache_spec(cfg, SHAPES["decode_32k"], ("data",), 16, MODEL)
+    assert cache["latent"] == cache["latent_dense"] == P(
+        None, ("data",), None, None)

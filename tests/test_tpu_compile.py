@@ -77,3 +77,29 @@ def test_portfolio_step_contractions_are_highest_precision(one_chip):
     assert len(dots) == 5
     for dot in dots:
         assert "precision = [HIGHEST, HIGHEST]" in dot, dot
+
+
+def test_deepseek_pair_step_compiles_for_v5e(one_chip):
+    """The ``dsv3-sweep`` cell's chunk step: the pair step over DeepSeek-V3's
+    58-row table, at the default chunk and ``stall_topk`` 8, fits one v5e
+    chip."""
+    from repro.perfmodel.evaluator import make_evaluator
+    from repro.perfmodel.workload import zoo_suite
+    wls, scen = zoo_suite(batch=32, seq=32768, tp=8, out_pos=1024,
+                          archs=("deepseek-v3",))
+    eng = SweepEngine(make_evaluator(wls, tier="proxy", scenarios=scen),
+                      stall_topk=8)
+    assert eng.chunk_size == 131_072 and eng.op_rows == 58
+    st = eng._fresh_state(0)
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
+                                    sharding=one_chip)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    filt = jax.ShapeDtypeStruct((eng.filter_size, 3), jnp.float32,
+                                sharding=one_chip)
+    compiled = eng._step.lower(jax.tree.map(spec, st["carry"]), scalar,
+                               scalar, filt).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 * 2 ** 30
