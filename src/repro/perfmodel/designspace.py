@@ -89,7 +89,7 @@ class DesignSpace:
     def size(self) -> int:
         return int(np.prod(self.cardinalities))
 
-    # ---- choice tables, padded to a rectangle for vectorized decode ----
+    # ---- choice table, padded to a rectangle for decode_np ----
     def choice_table(self) -> np.ndarray:
         """(n_params, max_choices) float64 table; padded with the last value."""
         k = int(self.cardinalities.max())
@@ -123,13 +123,23 @@ class DesignSpace:
     def decode(self, idx) -> Dict[str, jnp.ndarray]:
         """Index vectors -> dict of physical value arrays.
 
-        ``idx`` may be shape (n_params,) or (batch, n_params); outputs follow.
-        Fully traceable (gather from the padded choice table).
+        ``idx`` may be shape (..., n_params); outputs have shape (...,) in
+        the default float dtype.  Fully traceable: each parameter is a chain
+        of compare-selects over its own choices, elementwise work with no
+        table read (a gather from the padded table lowers to one scalar
+        slice per design and parameter).  An index at or past a parameter's
+        cardinality gives its last choice, as ``decode_np``'s padding does.
         """
-        idx = jnp.asarray(idx)
-        tab = jnp.asarray(self.choice_table())
-        vals = tab[jnp.arange(self.n_params), idx.astype(jnp.int32)]
-        return {name: vals[..., i] for i, name in enumerate(self.names)}
+        idx = jnp.asarray(idx).astype(jnp.int32)
+        dtype = jnp.result_type(float)
+        out = {}
+        for i, (name, ch) in enumerate(zip(self.names, self.choices)):
+            k = idx[..., i]
+            v = jnp.full(k.shape, ch[-1], dtype)
+            for j in range(len(ch) - 2, -1, -1):
+                v = jnp.where(k == j, jnp.asarray(ch[j], dtype), v)
+            out[name] = v
+        return out
 
     def decode_np(self, idx: np.ndarray) -> Dict[str, np.ndarray]:
         idx = np.asarray(idx)

@@ -1,4 +1,6 @@
 """Design-space encode/decode round-trip properties (hypothesis)."""
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -57,3 +59,48 @@ def test_sample_shape_and_range():
     s = SPACE.sample(rng, 1000)
     assert s.shape == (1000, SPACE.n_params)
     assert (s >= 0).all() and (s < SPACE.cardinalities[None, :]).all()
+
+
+_DECODE_JIT = jax.jit(SPACE.decode)
+
+
+def _decode_batches(param: int, index: int):
+    """Index arrays of shape (8,), (b, 8) and (b1, b2, 8): column ``param``
+    holds ``index``; the other columns run over 0..13, past each
+    cardinality too."""
+    k = SPACE.choice_table().shape[1]
+    rows = (np.arange(3 * k)[:, None] + 5 * np.arange(SPACE.n_params)) % k
+    rows[:, param] = index
+    rows = rows.astype(np.int32)
+    return rows[0], rows, rows.reshape(3, k, SPACE.n_params)
+
+
+@pytest.mark.parametrize("index", range(14))
+@pytest.mark.parametrize("param", SPACE.names)
+def test_decode_equals_padded_table(param, index):
+    """``decode`` gives the padded choice table's values, bit for bit, for
+    every choice and for indices past a parameter's cardinality (the last
+    choice), in the default float dtype with x64 off and on."""
+    tab = SPACE.choice_table()
+    assert tab.shape[1] == 14
+    for idx in _decode_batches(SPACE.names.index(param), index):
+        want = tab[np.arange(SPACE.n_params), idx]
+        for x64, dtype in ((False, np.float32), (True, np.float64)):
+            with jax.enable_x64(x64):
+                for fn in (SPACE.decode, _DECODE_JIT):
+                    got = fn(jnp.asarray(idx))
+                    assert sorted(got) == sorted(SPACE.names)
+                    for i, name in enumerate(SPACE.names):
+                        assert got[name].dtype == dtype
+                        assert got[name].shape == idx.shape[:-1]
+                        np.testing.assert_array_equal(
+                            np.asarray(got[name]),
+                            want[..., i].astype(dtype), strict=True)
+
+
+def test_decode_lowers_without_gather():
+    """The decode of a batch is elementwise selects, never a gather from
+    the choice table (one scalar slice per design and parameter)."""
+    idx = jnp.zeros((4096, SPACE.n_params), jnp.int32)
+    text = jax.jit(SPACE.decode).lower(idx).as_text()
+    assert "gather" not in text
