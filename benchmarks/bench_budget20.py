@@ -22,7 +22,7 @@ from repro.perfmodel.designspace import SPACE, A100_REFERENCE
 def run(budget: int = 20, trials: int = 3) -> List[str]:
     target = get_evaluator("target")
     proxy = get_evaluator("proxy")
-    oracle = get_evaluator("oracle", "compass")   # target-tier ground truth
+    oracle = get_evaluator("oracle", oracle_base="target")   # ground truth
 
     ref = target.objectives(SPACE.encode_nearest(A100_REFERENCE)[None, :])[0]
     lines = []

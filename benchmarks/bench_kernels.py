@@ -16,10 +16,6 @@ from repro.kernels.rwkv6_scan.ops import rwkv6_scan
 from repro.kernels.rwkv6_scan.ref import rwkv6_scan_ref
 from repro.kernels.ssm_scan.ops import ssm_scan
 from repro.kernels.ssm_scan.ref import ssm_scan_ref
-from repro.kernels.ppa_eval.ops import ppa_eval
-from repro.kernels.ppa_eval.ref import ppa_eval_ref
-from repro.perfmodel.designspace import SPACE
-from repro.perfmodel.workload import gpt3_layer_prefill
 
 
 def _time(fn, *args, reps: int = 3) -> float:
@@ -72,16 +68,6 @@ def run() -> List[str]:
     y = ssm_scan(u3, dt3, a3, B3, C3, interpret=True)
     err = float(jnp.abs(y - ref_fn(u3, dt3, a3, B3, C3)).max())
     lines.append(f"kernels,ssm_scan_oracle,{us:.1f},maxerr={err:.2e}")
-
-    wl = gpt3_layer_prefill()
-    idx = SPACE.sample(rng, 512)
-    t0 = time.time()
-    ref = ppa_eval_ref(idx, wl)
-    us = (time.time() - t0) * 1e6
-    out = ppa_eval(idx, wl, interpret=True)
-    err = float(np.abs(out["latency"] - ref[:, 0]).max()
-                / np.abs(ref[:, 0]).max())
-    lines.append(f"kernels,ppa_eval_512designs,{us:.1f},relerr={err:.2e}")
     return lines
 
 

@@ -2,11 +2,10 @@
 
 Three measurements behind the PR's acceptance bar:
 
-* **W-scaling curve** — fused-dispatch latency of the stacked evaluator as
-  the stacked workload count W grows (2 -> 20), against the looped
-  per-workload path at the same W: the stacked path's cost is near-flat in
-  W because the op-term model runs once over the deduped union
-  (``dedup_*`` lines report the union-vs-concat op counts).
+* **W-scaling curve** — fused-dispatch latency of the evaluator as the
+  stacked workload count W grows (2 -> 20): near-flat in W because the
+  op-term model runs once over the deduped union (``zoo_ops_*`` lines
+  report the union-vs-concat op counts).
 * **Portfolio sweep vs paper sweep** — the same id range swept with the
   2-workload paper evaluator and with the full zoo suite (10 scenarios,
   20 workloads, per-scenario fronts + stall seeds + robust front);
@@ -48,7 +47,7 @@ def run(full: bool = False, smoke: bool = False) -> List[str]:
     lines.append(f"portfolio,zoo_ops_concat,{stack.total_ops}")
     lines.append(f"portfolio,zoo_ops_unique,{stack.n_unique}")
 
-    # ---- W-scaling: stacked vs looped fused-dispatch latency ----
+    # ---- W-scaling: fused-dispatch latency against the workload count ----
     names = list(wls)
     idx = SPACE.sample(np.random.default_rng(0), 4096)
     w_axis = (2, 8, 20) if smoke else (2, 4, 8, 12, 16, 20)
@@ -57,14 +56,10 @@ def run(full: bool = False, smoke: bool = False) -> List[str]:
         sub = {nm: wls[nm] for nm in names[:w]}
         from repro.perfmodel.roofline import RooflineModel
         models = {nm: RooflineModel(wl) for nm, wl in sub.items()}
-        ms_stacked = _time_dispatch(
-            ModelEvaluator(models, stacked=True), idx) * 1e3
-        ms_looped = _time_dispatch(
-            ModelEvaluator(models, stacked=False), idx) * 1e3
+        ms_stacked = _time_dispatch(ModelEvaluator(models), idx) * 1e3
         if base_ms is None:
             base_ms = ms_stacked
         lines.append(f"portfolio,stacked_w{w}_ms,{ms_stacked:.2f}")
-        lines.append(f"portfolio,looped_w{w}_ms,{ms_looped:.2f}")
         lines.append(f"portfolio,stacked_w{w}_vs_w2,"
                      f"{ms_stacked / max(base_ms, 1e-9):.2f}")
 

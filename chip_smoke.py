@@ -17,9 +17,6 @@ child).  One chip, no arguments:
             target tiers vs the CPU device; one fused dispatch per call
   campaign  a sweep-seeded CampaignRunner and the paper's LuminaDSE, both
             at budget 20
-  kernel    the ppa_eval Pallas kernel compiled for the chip
-            (tpu_custom_call in the compiled HLO) vs the traced roofline
-            path, in the evaluator and in a sweep over 1,048,576 ids
 
 ``--chips 4`` runs only the multi-chip paths and what they are compared
 with: the sharded full-space sweep vs the one-chip sweep (identical), and
@@ -53,7 +50,7 @@ import numpy as np  # noqa: E402
 # done as one bf16 pass shows up near 1e-3, two orders above it.
 TOL = 1e-5
 N_SAMPLE = 4096            # sampled designs per objective comparison
-SUB_RANGE = 1 << 20        # zoo reference and kernel sweep: ids [0, 2**20)
+SUB_RANGE = 1 << 20        # zoo reference: ids [0, 2**20)
 BUDGET = 20
 
 
@@ -105,8 +102,7 @@ def cpu_twin(ev):
     ``jax.default_device(cpu)`` so every array it makes lives there."""
     from repro.perfmodel import ModelEvaluator
     models = {nm: type(m)(m.wl, m.space) for nm, m in ev.models.items()}
-    return ModelEvaluator(models, tier=ev.tier, backend=ev.backend,
-                          scenarios=ev.scenarios)
+    return ModelEvaluator(models, tier=ev.tier, scenarios=ev.scenarios)
 
 
 def reports_equal(a, b) -> bool:
@@ -153,20 +149,6 @@ def timed_sweep(eng, stop=None):
     eng.run(0, eng.chunk_size)
     first = time.perf_counter() - t0
     return first, eng.run(0, stop)
-
-
-def compiled_hlo(jitted, *args) -> str:
-    return jitted.lower(*args).compile().as_text()
-
-
-def step_args(eng):
-    """Concrete arguments of ``eng``'s chunk step for lowering."""
-    st = eng._fresh_state(0)
-    archives = st["archives"] if eng._portfolio else [st["archive"]]
-    rows = eng._pf_rows if eng._portfolio else None
-    filt = np.stack([eng._filter_from_archive(a, rows) for a in archives])
-    return (st["carry"], jnp.int32(0), jnp.int32(eng.size),
-            jnp.asarray(filt if eng._portfolio else filt[0]))
 
 
 # ---------------------------------------------------------------------------
@@ -422,7 +404,7 @@ def phase_campaign(sm, paper):
     # -- the paper's budget-20 loop on the target tier
     target = get_evaluator("target")
     dse_proxy = get_evaluator("proxy")
-    compass_oracle = get_evaluator("oracle", "compass")
+    compass_oracle = get_evaluator("oracle", oracle_base="target")
     ref = target.objectives(SPACE.encode_nearest(A100_REFERENCE)[None, :])[0]
     steps = []
     d0 = target.dispatches
@@ -446,53 +428,6 @@ def phase_campaign(sm, paper):
     sm.check("campaign", "lumina_evaluations", len(out.samples) == BUDGET)
     sm.check("campaign", "lumina_one_dispatch_per_step",
              int(per_step.max()) <= 1, per_step.tolist())
-
-
-def phase_kernel(sm):
-    from repro.perfmodel import get_evaluator
-    from repro.perfmodel.designspace import SPACE
-    from repro.perfmodel.sweep import SweepEngine
-    traced = get_evaluator("proxy")
-    pal = get_evaluator("proxy", "pallas")
-    sm.fact("kernel", "evaluator_backend", pal.backend)
-    rng = np.random.default_rng(3)
-    for b in (32, 4096):
-        fn = pal._fused_fn("objectives", pal.workloads).jitted
-        hlo = compiled_hlo(fn, jnp.zeros((b, SPACE.n_params), jnp.int32))
-        sm.check("kernel", f"evaluator_b{b}_tpu_custom_call",
-                 "tpu_custom_call" in hlo)
-        idx = SPACE.sample(rng, b)
-        err = max_rel(pal.objectives(idx), traced.objectives(idx))
-        sm.fact("kernel", f"evaluator_b{b}_max_rel", f"{err:.3e}")
-        sm.check("kernel", f"evaluator_b{b}_within_tol", err <= TOL,
-                 f"{err:.3e}")
-
-    eng = SweepEngine(pal, stall_topk=8)
-    sm.fact("kernel", "sweep_backend", eng.backend)
-    first, res = timed_sweep(eng, SUB_RANGE)
-    hlo = compiled_hlo(eng._step, *step_args(eng))
-    sm.check("kernel", "sweep_step_tpu_custom_call",
-             "tpu_custom_call" in hlo)
-    sm.fact("kernel", "sweep_ids", res.n_evaluated)
-    sm.fact("kernel", "sweep_first_chunk_s_incl_compile", f"{first:.2f}")
-    sm.fact("kernel", "sweep_warm_designs_per_s", f"{res.points_per_sec:.0f}")
-    ref = SweepEngine(traced, stall_topk=8).run(0, SUB_RANGE)
-    sm.fact("kernel", "sweep_n_superior", res.n_superior)
-    sm.check("kernel", "sweep_n_superior_matches_traced",
-             res.n_superior == ref.n_superior,
-             f"kernel {res.n_superior} traced {ref.n_superior}")
-
-    def obj_pal(ids):
-        return pal.objectives(SPACE.flat_to_idx(ids))
-
-    def obj_traced(ids):
-        return traced.objectives(SPACE.flat_to_idx(ids))
-
-    n_a, n_b, ok = front_ties_ok(res.pareto_ids, ref.pareto_ids,
-                                 obj_pal, obj_traced)
-    sm.fact("kernel", "sweep_front_only_kernel", n_a)
-    sm.fact("kernel", "sweep_front_only_traced", n_b)
-    sm.check("kernel", "sweep_fronts_agree_up_to_ties", ok)
 
 
 # ---------------------------------------------------------------------------
@@ -593,7 +528,6 @@ def main(argv=None) -> int:
         sm.phase("zoo", phase_zoo, cpu)
         sm.phase("dispatch", phase_dispatch, cpu)
         sm.phase("campaign", phase_campaign, paper)
-        sm.phase("kernel", phase_kernel)
     if sm.failures:
         print("FAILED: " + " ".join(sm.failures), file=sys.stderr)
         return 1

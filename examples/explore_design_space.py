@@ -22,15 +22,13 @@ def main() -> None:
     ap.add_argument("--budget", type=int, default=150)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=2048)
-    ap.add_argument("--backend", default=None,
-                    help="evaluator backend: roofline|pallas|auto")
     args = ap.parse_args()
 
     cfg = get_arch(args.arch)
     evaluator = make_evaluator({
         "ttft": from_arch(cfg, args.batch, args.seq, decode=False),
         "tpot": from_arch(cfg, args.batch, args.seq, decode=True),
-    }, backend=args.backend)
+    })
 
     ref = evaluator.objectives(SPACE.encode_nearest(A100_REFERENCE)[None, :])[0]
     print(f"workload: {args.arch}  A100 point: "

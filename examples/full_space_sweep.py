@@ -20,8 +20,6 @@ def main() -> None:
     ap.add_argument("--stop", type=int, default=None,
                     help="sweep only flat ids [0, STOP) instead of the full space")
     ap.add_argument("--chunk", type=int, default=131_072)
-    ap.add_argument("--backend", default="roofline",
-                    choices=["roofline", "pallas"])
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--checkpoint-every", type=int, default=None,
                     help="chunks between checkpoint writes")
@@ -32,7 +30,7 @@ def main() -> None:
     args = ap.parse_args()
 
     eng = SweepEngine(get_evaluator("proxy"), chunk_size=args.chunk,
-                      backend=args.backend, stall_topk=args.stall_topk)
+                      stall_topk=args.stall_topk)
     ref = eng.ref_point
     print(f"design space: {SPACE.size:,} points "
           f"({' x '.join(str(len(c)) for c in SPACE.choices)})")

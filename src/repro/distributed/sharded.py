@@ -202,7 +202,7 @@ _WORKER_EVALUATOR: Optional[ModelEvaluator] = None
 
 
 def _worker_spec(base: ModelEvaluator) -> bytes:
-    """(model class, workload, space, tier, backend) — everything a spawned
+    """(model class, workload, space, tier, scenarios) — everything a spawned
     worker needs to reconstruct an equivalent evaluator from scratch.
 
     These bytes are a cross-machine wire format (`repro.serve` workers
@@ -214,9 +214,7 @@ def _worker_spec(base: ModelEvaluator) -> bytes:
         "models": {nm: (type(m), m.wl) for nm, m in base.models.items()},
         "space": base.space,
         "tier": base.tier,
-        "backend": base.backend,
         "scenarios": getattr(base, "scenarios", None),
-        "stacked": getattr(base, "stacked", None),
     }, protocol=pickle.HIGHEST_PROTOCOL)
 
 
@@ -235,9 +233,7 @@ def evaluator_from_spec(spec_bytes: bytes, loads=None) -> ModelEvaluator:
     models = {nm: cls(wl, spec["space"])
               for nm, (cls, wl) in spec["models"].items()}
     return ModelEvaluator(models, tier=spec["tier"],
-                          backend=spec["backend"],
-                          scenarios=spec.get("scenarios"),
-                          stacked=spec.get("stacked"))
+                          scenarios=spec.get("scenarios"))
 
 
 def _process_init(spec_bytes: bytes) -> None:
@@ -540,10 +536,6 @@ class ShardedEvaluator:
     @property
     def models(self):
         return self.base.models
-
-    @property
-    def backend(self):
-        return getattr(self.base, "backend", None)
 
     @property
     def scenarios(self):

@@ -9,14 +9,10 @@ Kernels:
   flash_attention — tiled online-softmax attention (prefill hot spot)
   rwkv6_scan      — RWKV6 data-dependent-decay recurrence (Finch time-mix)
   ssm_scan        — Mamba selective scan (Jamba hot spot)
-  ppa_eval        — batched design-point PPA evaluation (the Lumina DSE
-                    substrate hot loop: one kernel call evaluates a block of
-                    candidate architectures against an operator table)
 """
 
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.rwkv6_scan.ops import rwkv6_scan
 from repro.kernels.ssm_scan.ops import ssm_scan
-from repro.kernels.ppa_eval.ops import ppa_eval
 
-__all__ = ["flash_attention", "rwkv6_scan", "ssm_scan", "ppa_eval"]
+__all__ = ["flash_attention", "rwkv6_scan", "ssm_scan"]

@@ -17,14 +17,12 @@ tiered Evaluator API** of :mod:`repro.perfmodel.evaluator`:
   ``proxy``   fast roofline models (paper Fig. 1/4/5) — acquisition tier
   ``target``  LLMCompass-calibrated models (paper §5.3, Table 4) — the
               budgeted high-fidelity tier
-  ``oracle``  the exhaustive 4.7M-point sweep front
+  ``oracle``  the exhaustive 4.7M-point sweep front of the proxy tier
+              (or ``oracle_base="target"``)
               (:class:`~repro.perfmodel.evaluator.OracleEvaluator`) for
               exact regret / PHV normalization
   =========  ==========================================================
 
-* a **backend registry** (``roofline`` | ``compass`` | ``pallas`` with
-  ``backend="auto"`` benchmark-driven selection) choosing the compute
-  substrate independently of the tier;
 * **workload suites** (``get_evaluator(suite="paper" | "zoo")``): the
   GPT-3 pair, or every assigned architecture config as a
   :class:`~repro.perfmodel.workload.Scenario` — all workloads stacked
@@ -32,10 +30,12 @@ tiered Evaluator API** of :mod:`repro.perfmodel.evaluator`:
   WorkloadStack`) so a single dispatch (and a single sweep pass) scores
   the whole zoo.
 
-The evaluator's traced path is *fused*: one jitted dispatch decodes the
+There is one evaluation path: the tier picks the model class, and the
+evaluator's traced path is *fused*: one jitted dispatch decodes the
 batch, derives hardware once, and evaluates every workload (TTFT + TPOT +
-stall attribution).  The request is batched end to end — K parallel
-campaigns' candidates ride one dispatch (see
+stall attribution) in one op-term pass over the deduped op union.  The
+request is batched end to end — K parallel campaigns' candidates ride one
+dispatch (see
 :class:`~repro.core.campaign.CampaignRunner`).  The pre-PR-2 per-model
 shims (``eval_ppa`` / ``objectives`` / pair signatures) have been removed.
 
@@ -72,8 +72,7 @@ from repro.perfmodel.evaluator import (Evaluator, EvalRequest, PPAReport,
                                        ModelEvaluator, OracleEvaluator,
                                        RowCache, get_evaluator,
                                        make_evaluator, as_evaluator,
-                                       pair_view, register_backend,
-                                       backend_names, TIERS, DETAILS, SUITES)
+                                       pair_view, TIERS, DETAILS, SUITES)
 from repro.perfmodel.sweep import SweepEngine, SweepResult
 
 __all__ = [
@@ -85,7 +84,6 @@ __all__ = [
     "attribute_stalls", "STALL_CLASSES",
     "Evaluator", "EvalRequest", "PPAReport", "ModelEvaluator",
     "OracleEvaluator", "RowCache", "get_evaluator", "make_evaluator",
-    "as_evaluator", "pair_view", "register_backend", "backend_names",
-    "TIERS", "DETAILS", "SUITES",
+    "as_evaluator", "pair_view", "TIERS", "DETAILS", "SUITES",
     "SweepEngine", "SweepResult",
 ]

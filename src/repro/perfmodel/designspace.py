@@ -3,7 +3,7 @@
 Each design point is an 8-vector of *choice indices* (int32), one per
 parameter, in the canonical order of :data:`PARAM_NAMES`.  Index-space is the
 representation used everywhere (search algorithms, trajectory memory, the
-Pallas ``ppa_eval`` kernel); :meth:`DesignSpace.decode` maps indices to
+sweep's on-device unrank); :meth:`DesignSpace.decode` maps indices to
 physical values.
 
 Total cardinality: 4 * 14 * 4 * 6 * 6 * 7 * 7 * 12 = 4,741,632  (~4.7M,
@@ -22,7 +22,7 @@ import numpy as np
 import jax.numpy as jnp
 
 # Canonical parameter order.  KEEP STABLE: trajectory memory, the DSE
-# benchmark generator and the Pallas kernel all index by position.
+# benchmark generator and the sweep checkpoints all index by position.
 PARAM_NAMES: tuple = (
     "link_count",        # interconnect links per GPU
     "core_count",        # number of cores (SM / TensorCore-tile analogue)

@@ -17,9 +17,6 @@ some fidelity tier*.  This module is that service boundary:
   computed inside the same XLA executable, instead of two-to-four separate
   model calls.  Compiled executables live in the same workload-keyed jit
   cache the models use, so every evaluator in a process shares them.
-* a **backend registry** (``roofline`` | ``compass`` | ``pallas``) with
-  benchmark-driven auto-selection (``backend="auto"`` times the candidates
-  on a probe batch and keeps the fastest for this process);
 * **tiers**: ``proxy`` (cheap roofline acquisition tier), ``target``
   (LLMCompass-calibrated budgeted tier) and ``oracle`` — the exhaustive
   :class:`~repro.perfmodel.sweep.SweepEngine` front wrapped as
@@ -39,15 +36,14 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from collections import OrderedDict
 from typing import Callable, Dict, Mapping, Optional, Protocol, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.obs.trace import NOOP
+from repro.perfmodel.compass import CompassModel
 from repro.perfmodel.critical_path import StallReport, build_report
 from repro.perfmodel.designspace import DesignSpace, SPACE
 from repro.perfmodel.hardware import derive_hardware
@@ -239,104 +235,20 @@ class RowCache:
 
 
 # ---------------------------------------------------------------------------
-# backend registry
+# tiers
 # ---------------------------------------------------------------------------
 
-@dataclasses.dataclass(frozen=True)
-class BackendSpec:
-    name: str
-    model_cls: type            # RooflineModel subclass providing the op terms
-    kernel: bool = False       # route the objectives dispatch through the
-                               # Pallas ppa_eval kernel (TPU-native)
-
-_BACKENDS: Dict[str, BackendSpec] = {}
-
-
-def register_backend(name: str, model_cls: type, *, kernel: bool = False) -> None:
-    _BACKENDS[name] = BackendSpec(name=name, model_cls=model_cls, kernel=kernel)
-
-
-def backend_names() -> Tuple[str, ...]:
-    return tuple(_BACKENDS)
-
-
-def _backend(name: str) -> BackendSpec:
-    if name not in _BACKENDS:
-        raise ValueError(f"unknown backend {name!r}; "
-                         f"registered: {sorted(_BACKENDS)}")
-    return _BACKENDS[name]
-
-
-# tier -> default backend for model construction
-TIER_BACKEND = {"proxy": "roofline", "target": "compass"}
-
-_AUTO_CACHE: Dict[tuple, str] = {}
-
-
-def _bare_roofline(models: Mapping[str, RooflineModel]) -> bool:
-    return all((m.op_overhead_s, m.nonoverlap, m.mem_efficiency) == (0.0, 0.0, 1.0)
-               for m in models.values())
+# the model class that computes each analytical tier's op terms
+TIER_MODEL = {"proxy": RooflineModel, "target": CompassModel}
 
 
 def homogeneous_models(models: Mapping[str, RooflineModel]) -> bool:
     """True when every model shares one op-term implementation (class +
-    compass knobs) — the eligibility rule for the stacked evaluator path
-    AND the portfolio sweep's union-level chunk math (one definition, two
-    consumers)."""
+    compass knobs): the union pass computes every workload's op terms
+    with one model's math, so :class:`ModelEvaluator` refuses any other
+    set."""
     return len({(type(m), m.op_overhead_s, m.nonoverlap, m.mem_efficiency)
                 for m in models.values()}) == 1
-
-
-def resolve_backend(backend: Optional[str],
-                    models: Mapping[str, RooflineModel]) -> str:
-    """Map None/"auto" to a concrete backend for these models.
-
-    "auto" benchmarks the candidate fused objective dispatches on a probe
-    batch and keeps the fastest (memoized per process + device platform).
-    Only bare-roofline models are eligible for the Pallas kernel; compass-
-    tier knobs force the traced roofline path.
-    """
-    if backend is None:
-        return "roofline"
-    if backend != "auto":
-        spec = _backend(backend)
-        if spec.kernel and not _bare_roofline(models):
-            raise ValueError(
-                f"backend={backend!r} implements the bare roofline tier; "
-                "these models carry compass-tier knobs the kernel ignores")
-        return backend
-    if not _bare_roofline(models):
-        return "roofline"
-    key = (jax.default_backend(),
-           tuple(_workload_fingerprint(m.wl) for m in models.values()))
-    cached = _AUTO_CACHE.get(key)
-    if cached is None:
-        cached = _benchmark_backends(models)
-        _AUTO_CACHE[key] = cached
-    return cached
-
-
-def _benchmark_backends(models: Mapping[str, RooflineModel],
-                        probe: int = 1024) -> str:
-    """Time each kernel-capable candidate's fused objectives dispatch.
-
-    A candidate that fails to build or run raises: skipping it would hide
-    a broken backend behind a silently different choice."""
-    best_name, best_t = "roofline", np.inf
-    rng = np.random.default_rng(0)
-    space = next(iter(models.values())).space
-    idx = space.sample(rng, probe)
-    for name, spec in _BACKENDS.items():
-        if spec.model_cls is not type(next(iter(models.values()))) and not spec.kernel:
-            continue
-        ev = ModelEvaluator(models, backend=name)
-        ev.objectives(idx)                          # compile + warm
-        t0 = time.perf_counter()
-        ev.objectives(idx)
-        dt = time.perf_counter() - t0
-        if dt < best_t:
-            best_name, best_t = name, dt
-    return best_name
 
 
 # ---------------------------------------------------------------------------
@@ -363,9 +275,9 @@ class ModelEvaluator:
     """
 
     def __init__(self, models: Mapping[str, RooflineModel], *,
-                 tier: str = "proxy", backend: Optional[str] = None,
+                 tier: str = "proxy",
                  scenarios: Optional[Tuple[Scenario, ...]] = None,
-                 stacked: Optional[bool] = None, tracer=None):
+                 tracer=None):
         if not models:
             raise ValueError("need at least one workload model")
         self.models: Dict[str, RooflineModel] = dict(models)
@@ -375,19 +287,15 @@ class ModelEvaluator:
             if len(keys) > 1:
                 raise ValueError("all workload models must share one design space")
         self.space: DesignSpace = next(iter(self.models.values())).space
-        self.tier = tier
-        self.backend = resolve_backend(backend, self.models)
-        self.scenarios = scenarios
-        # stacked path: ONE op-term pass over the deduped union of all
-        # workloads' op tables instead of a per-workload traced loop —
-        # bit-identical, near-flat cost in the workload count.  Eligible
-        # when every model shares the op-term math (class + compass knobs).
-        eligible = homogeneous_models(self.models)
-        if stacked and not eligible:
+        # ONE op-term pass over the deduped union of all workloads' op
+        # tables: near-flat cost in the workload count, and it needs every
+        # model to share the op-term math (class + compass knobs)
+        if not homogeneous_models(self.models):
             raise ValueError(
-                "stacked=True needs every workload model to share one class "
-                "and compass-knob set (their op terms fuse into one pass)")
-        self.stacked = eligible if stacked is None else bool(stacked)
+                "every workload model must share one class and compass-knob "
+                "set (their op terms fuse into one pass)")
+        self.tier = tier
+        self.scenarios = scenarios
         self.dispatches = 0            # fused jitted dispatch count
         self.tracer = tracer if tracer is not None else NOOP
         self._fns: Dict[tuple, PackedFn] = {}
@@ -407,8 +315,7 @@ class ModelEvaluator:
         return stack
 
     def _cache_key(self, detail: str, names: Tuple[str, ...]) -> tuple:
-        return ("stacked" if self.stacked else "fused", detail, self.backend,
-                _space_key(self.space),
+        return (detail, _space_key(self.space),
                 tuple((nm, type(m).__qualname__, m._tp,
                        (m.op_overhead_s, m.nonoverlap, m.mem_efficiency),
                        _workload_fingerprint(m.wl))
@@ -422,67 +329,22 @@ class ModelEvaluator:
         key = self._cache_key(detail, names)
         fn = _JIT_CACHE.get(key)
         if fn is None:
-            if self.backend != "roofline" and _backend(self.backend).kernel \
-                    and detail == "objectives":
-                fn = PackedFn(self._build_kernel_objectives(names))
-            else:
-                fn = PackedFn(self._build_traced(detail, names))
+            fn = PackedFn(self._build_traced(detail, names))
             _JIT_CACHE[key] = fn
         self._fns[(detail, names)] = fn
         return fn
 
     def _build_traced(self, detail: str, names: Tuple[str, ...]) -> Callable:
-        models = {nm: self.models[nm] for nm in names}
-        if self.stacked:
-            stack = self._stack(names)
-            rep_model = models[names[0]]
-
-            def fused(idx: jnp.ndarray) -> Dict:
-                vals = self.space.decode(idx)        # once per batch
-                hw = derive_hardware(vals)           # once per batch
-                hwb = {kk: vv[:, None] for kk, vv in hw.items()}
-                return {"area": hw["area_mm2"],
-                        "per_workload": stacked_workload_batches(
-                            rep_model, stack, hwb, detail,
-                            materialize_objectives=True)}
-
-            return fused
-
-        wl_detail = "objectives+sink" if detail == "objectives" else detail
+        stack = self._stack(names)
+        rep_model = self.models[names[0]]
 
         def fused(idx: jnp.ndarray) -> Dict:
             vals = self.space.decode(idx)            # once per batch
             hw = derive_hardware(vals)               # once per batch
             hwb = {kk: vv[:, None] for kk, vv in hw.items()}
-            out = {"area": hw["area_mm2"]}
-            out["per_workload"] = {
-                nm: m._workload_batch(hwb, wl_detail)
-                for nm, m in models.items()}
-            return out
-
-        return fused
-
-    def _build_kernel_objectives(self, names: Tuple[str, ...]) -> Callable:
-        """Objectives dispatch through the Pallas ppa_eval kernel."""
-        from repro.kernels.ppa_eval.kernel import ppa_eval_fwd
-        from repro.kernels.ppa_eval.ref import op_table
-        models = {nm: self.models[nm] for nm in names}
-        tables = {nm: jnp.asarray(op_table(m.wl), jnp.float32)
-                  for nm, m in models.items()}
-        interpret = jax.default_backend() != "tpu"
-
-        def fused(idx: jnp.ndarray) -> Dict:
-            vals = self.space.decode(idx)
-            dv = jnp.stack([vals[n] for n in self.space.names],
-                           axis=1).astype(jnp.float32)
-            per, area = {}, None
-            for nm, m in models.items():
-                o = ppa_eval_fwd(dv, tables[nm], tp=float(m.wl.tp),
-                                 block_b=min(256, dv.shape[0]),
-                                 interpret=interpret)
-                per[nm] = {"latency": o[:, 0]}
-                area = o[:, 5]
-            return {"area": area, "per_workload": per}
+            return {"area": hw["area_mm2"],
+                    "per_workload": stacked_workload_batches(
+                        rep_model, stack, hwb, detail)}
 
         return fused
 
@@ -549,7 +411,7 @@ class OracleEvaluator:
     ``oracle_store=`` opts into the persistent oracle store: ``True``
     uses ``~/.cache/repro-oracle/``, a string names a directory.  The
     sweep artifact is keyed by the engine's configuration fingerprint
-    (space cards, backend, workload fingerprints, model classes, stop +
+    (space cards, workload fingerprints, model classes, stop +
     sweep knobs), so a repeat OracleEvaluator anywhere on the machine is
     an O(1) ``load_sweep_result`` instead of a re-sweep; a corrupt
     artifact is quarantined and re-swept, never trusted.
@@ -681,25 +543,23 @@ class OracleEvaluator:
 # ---------------------------------------------------------------------------
 
 def make_evaluator(workloads: Mapping[str, "object"], *, tier: str = "proxy",
-                   backend: Optional[str] = None,
                    space: DesignSpace = SPACE,
-                   scenarios: Optional[Tuple[Scenario, ...]] = None,
-                   stacked: Optional[bool] = None) -> ModelEvaluator:
+                   scenarios: Optional[Tuple[Scenario, ...]] = None
+                   ) -> ModelEvaluator:
     """Build a ModelEvaluator from {name: Workload} at a fidelity tier."""
-    if tier not in TIER_BACKEND:
-        raise ValueError(f"tier must be one of {sorted(TIER_BACKEND)} here; "
+    if tier not in TIER_MODEL:
+        raise ValueError(f"tier must be one of {sorted(TIER_MODEL)} here; "
                          "use get_evaluator('oracle') for the oracle tier")
-    cls = _backend(TIER_BACKEND[tier]).model_cls
+    cls = TIER_MODEL[tier]
     models = {nm: cls(wl, space) for nm, wl in workloads.items()}
-    return ModelEvaluator(models, tier=tier, backend=backend,
-                          scenarios=scenarios, stacked=stacked)
+    return ModelEvaluator(models, tier=tier, scenarios=scenarios)
 
 
 _PAPER_EVALUATORS: Dict[tuple, "Evaluator"] = {}
 
 
-def get_evaluator(tier: str = "proxy", backend: Optional[str] = None,
-                  *, oracle_stop: Optional[int] = None,
+def get_evaluator(tier: str = "proxy", *, oracle_base: str = "proxy",
+                  oracle_stop: Optional[int] = None,
                   oracle_store=None,
                   workers: int = 1, mode: str = "auto",
                   suite: str = "paper") -> Evaluator:
@@ -707,9 +567,10 @@ def get_evaluator(tier: str = "proxy", backend: Optional[str] = None,
 
     tier="proxy"  -> roofline models (cheap acquisition tier);
     tier="target" -> compass models (the budgeted high-fidelity tier);
-    tier="oracle" -> OracleEvaluator over the chosen backend's models
-                     (default roofline), exposing the exhaustive front.
-    backend: "roofline" | "compass" | "pallas" | "auto" | None.
+    tier="oracle" -> OracleEvaluator over the ``oracle_base`` tier's
+                     evaluator, exposing the exhaustive front.
+    oracle_base: "proxy" (default) | "target" — the tier the oracle
+             sweeps; ignored at the other tiers.
     oracle_store: opt-in persistent sweep-artifact store for the oracle
              tier (``True`` = ``~/.cache/repro-oracle/``, or a directory
              path) — repeat oracle construction loads the stored front
@@ -729,33 +590,33 @@ def get_evaluator(tier: str = "proxy", backend: Optional[str] = None,
         raise ValueError(f"tier must be one of {TIERS}, got {tier!r}")
     if suite not in SUITES:
         raise ValueError(f"suite must be one of {SUITES}, got {suite!r}")
+    if oracle_base not in TIER_MODEL:
+        raise ValueError(f"oracle_base must be one of {tuple(TIER_MODEL)}, "
+                         f"got {oracle_base!r}")
     from repro.distributed.sharded import MODES  # leaf dep (mode validation)
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
     workers = max(1, int(workers))
     if workers == 1:
         mode = "auto"      # inert knobs: collapse onto the memoized base key
-    key = (tier, backend, oracle_stop, workers, mode, suite,
+    if tier != "oracle":
+        oracle_base = "proxy"  # inert knob: collapse onto one key
+    key = (tier, oracle_base, oracle_stop, workers, mode, suite,
            None if not oracle_store else str(oracle_store))
     cached = _PAPER_EVALUATORS.get(key)
     if cached is not None:
         return cached
     from repro.perfmodel.workload import paper_suite, zoo_suite
     if tier == "oracle":
-        base_backend = backend or "roofline"
-        base_tier = "target" if base_backend == "compass" else "proxy"
-        base = get_evaluator(base_tier, base_backend,
-                             workers=workers, mode=mode, suite=suite)
+        base = get_evaluator(oracle_base, workers=workers, mode=mode,
+                             suite=suite)
         ev: Evaluator = OracleEvaluator(base, stop=oracle_stop,
                                         oracle_store=oracle_store)
     else:
-        model_backend = backend if backend not in (None, "auto", "pallas") \
-            else TIER_BACKEND[tier]
-        cls = _backend(model_backend).model_cls
+        cls = TIER_MODEL[tier]
         wls, scenarios = (paper_suite() if suite == "paper" else zoo_suite())
         models = {nm: cls(wl) for nm, wl in wls.items()}
-        ev = ModelEvaluator(models, tier=tier, backend=backend,
-                            scenarios=scenarios)
+        ev = ModelEvaluator(models, tier=tier, scenarios=scenarios)
         if workers > 1:
             from repro.distributed.sharded import ShardedEvaluator  # leaf dep
             ev = ShardedEvaluator(ev, workers=workers, mode=mode)
@@ -795,10 +656,8 @@ def pair_view(evaluator, names: Tuple[str, str]) -> Evaluator:
     if unknown:
         raise KeyError(f"unknown workloads {sorted(unknown)}; "
                        f"have {tuple(models)}")
-    backend = getattr(evaluator, "backend", None)
     return ModelEvaluator({nm: models[nm] for nm in names},
                           tier=evaluator.tier,
-                          backend=backend if backend in _BACKENDS else None,
                           tracer=getattr(evaluator, "tracer", None))
 
 
@@ -818,10 +677,3 @@ def as_evaluator(obj) -> Evaluator:
     if isinstance(obj, RooflineModel):
         return evaluator_for_model(obj)
     raise TypeError(f"cannot interpret {type(obj).__name__} as an Evaluator")
-
-
-# default registry entries
-register_backend("roofline", RooflineModel)
-from repro.perfmodel.compass import CompassModel  # noqa: E402  (leaf import)
-register_backend("compass", CompassModel)
-register_backend("pallas", RooflineModel, kernel=True)

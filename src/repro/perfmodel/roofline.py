@@ -27,7 +27,7 @@ the artifact with ``--write`` if the edge change is intentional.
 from __future__ import annotations
 
 import hashlib
-from typing import Callable, Dict, Mapping, Optional, Tuple, Union
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -290,9 +290,10 @@ def _attribute(t: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 
 class RooflineModel:
-    """Per-workload op-term model: the traced building block every
-    :class:`~repro.perfmodel.evaluator.ModelEvaluator` (and the sweep
-    engine's chunk step) composes via :meth:`_workload_batch`.
+    """Per-workload op-term model: the traced building block that every
+    :class:`~repro.perfmodel.evaluator.ModelEvaluator` composes via
+    :func:`stacked_workload_batches` and the sweep engine's pair chunk step
+    via :meth:`_workload_batch`.
 
     Evaluate through the unified Evaluator contract — a model instance on
     its own is just the op-term provider for one workload.
@@ -317,7 +318,7 @@ class RooflineModel:
         """Per-op time terms for (B, 1)-broadcast hardware dicts.
 
         Shared by the full eval path and the lean sweep/objectives path.
-        ``ops`` overrides the model's own op table — the stacked path feeds
+        ``ops`` overrides the model's own op table — the union pass feeds
         the deduped union of a :class:`~repro.perfmodel.workload.
         WorkloadStack` through the same traced math (``t_unit`` is the
         count-free per-op time the gather reassembly multiplies back out).
@@ -364,9 +365,9 @@ class RooflineModel:
                         detail: str = "stalls") -> Dict[str, jnp.ndarray]:
         """Per-workload traced outputs for (B, 1)-broadcast hardware arrays.
 
-        This is the unit the fused :class:`~repro.perfmodel.evaluator`
-        dispatch composes: the space decode and hardware derivation happen
-        ONCE per batch while each workload model contributes its op terms.
+        This is the unit the sweep's pair chunk step composes: the space
+        decode and hardware derivation happen ONCE per chunk while each
+        workload model contributes its op terms.
 
         detail: "objectives" -> latency only; "ppa" adds the per-op
         breakdown; "stalls" adds stall attribution on top of "ppa".
@@ -375,13 +376,6 @@ class RooflineModel:
         latency = t["t_op"].sum(axis=1)
         if detail == "objectives":
             return {"latency": latency}
-        if detail == "objectives+sink":
-            # evaluator path: emit t_op so the latency reduce consumes a
-            # materialized buffer exactly as at "ppa"/"stalls" (XLA's fused
-            # producer+reduce drifts a ULP on some op tables); the sweep's
-            # on-device step keeps plain "objectives" (the sink would be
-            # dead code there anyway)
-            return {"latency": latency, "_sink": t["t_op"]}
         count = t["count"]
         out = {
             "latency": latency,
@@ -409,8 +403,7 @@ class RooflineModel:
 def stacked_workload_batches(model: RooflineModel,
                              stack: "W.WorkloadStack",
                              hwb: Dict[str, jnp.ndarray],
-                             detail: Union[str, Mapping[str, str]] = "stalls",
-                             materialize_objectives: bool = False,
+                             detail: str = "stalls",
                              ) -> Dict[str, Dict[str, jnp.ndarray]]:
     """Every workload's ``_workload_batch`` outputs from ONE op-term pass.
 
@@ -418,23 +411,21 @@ def stacked_workload_batches(model: RooflineModel,
     workload in the stack must share them); its :meth:`RooflineModel.
     _op_terms` runs once over ``stack.unique`` (count-free ``t_unit``), and
     each workload's per-op arrays are reassembled by gathering its rows out
-    of the union and multiplying its own counts back in.  Because every
-    per-op value is elementwise in the op fields and the per-workload
-    reductions run over the same (B, n_ops_w) arrays in the same op order,
-    the result is BIT-IDENTICAL to looping ``_workload_batch`` per workload
-    — with O(n_unique) instead of O(sum n_ops_w) traced op-term cost.
+    of the union and multiplying its own counts back in: O(n_unique)
+    instead of O(sum n_ops_w) traced op-term cost.  Every per-op value is
+    elementwise in the op fields, so it equals the workload's own
+    ``_workload_batch`` value; a latency sum may differ from another
+    executable's in the last bit, as XLA fuses the reduce differently.
 
-    ``detail`` is one level for all workloads or a per-workload mapping
-    (the portfolio sweep attributes stalls only on prefill workloads).
+    ``detail`` is one level for all workloads.
 
-    ``materialize_objectives``: at the "objectives" level, also emit each
-    workload's per-op times under a ``"_sink"`` key.  At "ppa"/"stalls"
-    ``t_op`` is an executable OUTPUT, and XLA's materialized-buffer
-    reduction is what the looped path computes; the objectives-only
-    executable otherwise fuses gather+multiply into the latency reduce and
-    drifts a ULP.  The evaluator path sets this (bit-identity across
-    detail levels and vs the looped path is part of its contract); the
-    sweep's on-device step keeps the fully fused reduce.
+    At the "objectives" level each workload also emits its per-op times
+    under a ``"_sink"`` key.  At "ppa"/"stalls" ``t_op`` is an executable
+    OUTPUT, and the latency reduce reads a materialized buffer; without
+    the sink the objectives-only executable fuses gather+multiply into the
+    latency reduce and drifts a ULP, so the "objectives" report would no
+    longer be bit-identical to the latencies of the "ppa" and "stalls"
+    reports.
     """
     ones = np.ones(stack.n_unique, dtype=np.float64)
     uops = {kk: jnp.asarray(vv) for kk, vv in stack.unique.items()}
@@ -442,14 +433,12 @@ def stacked_workload_batches(model: RooflineModel,
     t = model._op_terms(hwb, ops=uops)
     out: Dict[str, Dict[str, jnp.ndarray]] = {}
     for nm in stack.names:
-        d = detail if isinstance(detail, str) else detail[nm]
         mp = jnp.asarray(stack.op_map[nm])
         cnt = jnp.asarray(stack.counts[nm])[None, :]
         t_op = t["t_unit"][:, mp] * cnt
         latency = t_op.sum(axis=1)
-        if d == "objectives":
-            out[nm] = ({"latency": latency, "_sink": t_op}
-                       if materialize_objectives else {"latency": latency})
+        if detail == "objectives":
+            out[nm] = {"latency": latency, "_sink": t_op}
             continue
         ow = {
             "latency": latency,
@@ -458,7 +447,7 @@ def stacked_workload_batches(model: RooflineModel,
             "t_memory": t["t_memory"][:, mp] * cnt,
             "t_comm": t["t_comm"][:, mp] * cnt,
         }
-        if d == "stalls":
+        if detail == "stalls":
             tw = {
                 "t_op": t_op,
                 "t_compute": t["t_compute"][:, mp],

@@ -3,8 +3,8 @@
 The paper's substrate claim is that vectorized PPA evaluation makes the
 *entire* 4,741,632-point design space cheaper to evaluate than a handful of
 LLMCompass samples.  :class:`SweepEngine` delivers that as a production
-path: the flat id range is streamed through the jitted roofline model (or
-the Pallas ``ppa_eval`` kernel) in fixed-size chunks, with
+path: the flat id range is streamed through the jitted roofline model in
+fixed-size chunks, with
 
 * mixed-radix unranking **on device** — no host-side ``flat_to_idx``
   materialization of 4.7M index vectors;
@@ -23,8 +23,7 @@ the Pallas ``ppa_eval`` kernel) in fixed-size chunks, with
   format), and the host merges top-k, per-stall-class seeds and the Pareto
   archive — reproducing the single-process result exactly;
 * ``chunk_size="auto"``: a short timed probe over ``chunk_candidates``
-  picks the fastest chunk size for this process (memoized), the same
-  benchmark-driven selection ``backend="auto"`` uses for backends;
+  picks the fastest chunk size for this process (memoized);
 * **portfolio mode**: an evaluator carrying multiple
   :class:`~repro.perfmodel.workload.Scenario`\\ s (e.g.
   ``get_evaluator(suite="zoo")``) streams the id range ONCE — one stacked
@@ -44,7 +43,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import math
 import os
 import time
 import warnings
@@ -61,8 +59,7 @@ from repro.obs.trace import NOOP
 from repro.runtime.fault import RetryPolicy, run_with_retries
 from repro.perfmodel.designspace import DesignSpace, SPACE, A100_REFERENCE
 from repro.perfmodel.hardware import derive_hardware
-from repro.perfmodel.roofline import (RooflineModel, _dominant_class,
-                                      _workload_fingerprint)
+from repro.perfmodel.roofline import _dominant_class, _workload_fingerprint
 from repro.perfmodel.workload import WorkloadStack
 
 _FMT_VERSION = 3       # v3 adds portfolio (multi-scenario) checkpoints
@@ -72,7 +69,7 @@ ROBUST = ("worst", "geomean")
 # stall classes in carry order (matches critical_path.STALL_CLASSES)
 _N_STALL = 4
 
-# chunk_size="auto" probe results, memoized per (platform, backend, config)
+# chunk_size="auto" probe results, memoized per (platform, config)
 _CHUNK_AUTO_CACHE: Dict[tuple, int] = {}
 
 
@@ -202,11 +199,11 @@ class SweepEngine:
 
     Parameters
     ----------
-    ttft_model, tpot_model:
-        Either a two-workload :class:`~repro.perfmodel.evaluator.
-        ModelEvaluator` as the single first argument, or a legacy
-        RooflineModel/CompassModel pair for the two latency objectives
-        (area comes from the shared area model).
+    evaluator:
+        A :class:`~repro.perfmodel.evaluator.ModelEvaluator` of two or
+        more workloads.  With more than one scenario it is swept as a
+        portfolio; otherwise its first two workloads are the two latency
+        objectives (area comes from the shared area model).
     stall_topk:
         When > 0, the chunk step also attributes stalls (TTFT workload) on
         device and keeps the `stall_topk` best designs per dominant stall
@@ -222,9 +219,9 @@ class SweepEngine:
         a latency-minimal max-area corner.
     chunk_size:
         Designs per device step, or ``"auto"`` to pick the fastest of
-        ``chunk_candidates`` by a short timed probe (memoized per process,
-        like ``backend="auto"``).  Rounded up to a multiple of the device
-        count when sharding.
+        ``chunk_candidates`` by a short timed probe (memoized per
+        process).  Rounded up to a multiple of the device count when
+        sharding.
     topk:
         Running best-k designs kept per objective.
     filter_size:
@@ -237,11 +234,6 @@ class SweepEngine:
     archive_capacity:
         Bound on the host Pareto archive; overflow prunes by crowding
         distance and marks the result ``archive_truncated``.
-    backend:
-        "roofline" inlines the models' lean jitted objectives path;
-        "pallas" routes chunk evaluation through the ``ppa_eval`` Pallas
-        kernel (TPU-native; interpreted elsewhere, so CPU sweeps should
-        keep the default).
     shard:
         Shard the id range over all local devices (no-op on one device).
     registry / tracer:
@@ -258,62 +250,30 @@ class SweepEngine:
         ``sweep.reduce``.
     """
 
-    def __init__(self, ttft_model, tpot_model: Optional[RooflineModel] = None,
-                 space: DesignSpace = SPACE, *,
+    def __init__(self, evaluator, *,
                  chunk_size: Union[int, str, None] = None, topk: int = 16,
                  filter_size: int = 128, local_filter: int = 32,
                  archive_capacity: Union[int, str, None] = 16_384,
                  ref_point: Optional[np.ndarray] = None,
-                 backend: str = "roofline", shard: bool = False,
+                 shard: bool = False,
                  stall_topk: int = 0, stall_rank: str = "ttft",
                  robust: str = "worst",
                  chunk_candidates: Tuple[int, ...] = (65_536, 131_072,
                                                       262_144),
                  registry: Optional[MetricsRegistry] = None,
                  tracer=None):
-        evaluator = None
-        scenarios = None
-        if tpot_model is None and hasattr(ttft_model, "models"):
-            # unified-API construction: SweepEngine(evaluator)
-            evaluator = ttft_model
-            if len(evaluator.workloads) < 2:
-                raise ValueError("sweep needs a two-workload evaluator "
-                                 "(ttft + tpot)")
-            scenarios = getattr(evaluator, "scenarios", None)
-            if scenarios is not None and len(scenarios) > 1:
-                if backend != "roofline":
-                    raise ValueError("portfolio sweeps run on the traced "
-                                     "roofline path; backend must stay "
-                                     "'roofline'")
-                if getattr(evaluator, "backend", None) == "pallas":
-                    raise ValueError("portfolio sweeps need a traced-backend "
-                                     "evaluator, not 'pallas'")
-            else:
-                scenarios = None
-            ttft_model = evaluator.models[evaluator.workloads[0]]
-            tpot_model = evaluator.models[evaluator.workloads[1]]
-            space = evaluator.space
-            if backend == "roofline" and evaluator.backend == "pallas":
-                backend = "pallas"
-        elif tpot_model is None:
-            raise TypeError("pass a ModelEvaluator or a (ttft, tpot) pair")
-        if backend not in ("roofline", "pallas"):
-            raise ValueError(f"unknown backend {backend!r}")
-        if backend == "pallas":
-            for m in (ttft_model, tpot_model):
-                if (m.op_overhead_s, m.nonoverlap, m.mem_efficiency) != (0.0, 0.0, 1.0):
-                    raise ValueError(
-                        "backend='pallas' implements the bare roofline tier; "
-                        f"{type(m).__name__} carries compass-tier knobs the "
-                        "kernel ignores — use backend='roofline'")
-        self.ttft_model = ttft_model
-        self.tpot_model = tpot_model
-        if evaluator is None:
-            from repro.perfmodel.evaluator import ModelEvaluator
-            evaluator = ModelEvaluator({"ttft": ttft_model,
-                                        "tpot": tpot_model})
+        if not hasattr(evaluator, "models"):
+            raise TypeError("pass a ModelEvaluator")
+        if len(evaluator.workloads) < 2:
+            raise ValueError("sweep needs a two-workload evaluator "
+                             "(ttft + tpot)")
+        scenarios = getattr(evaluator, "scenarios", None)
+        if scenarios is not None and len(scenarios) < 2:
+            scenarios = None
         self.evaluator = evaluator
-        self.space = space
+        self.ttft_model = evaluator.models[evaluator.workloads[0]]
+        self.tpot_model = evaluator.models[evaluator.workloads[1]]
+        self.space = space = evaluator.space
         self.size = space.size
         self.topk = int(topk)
         self.stall_topk = int(stall_topk)
@@ -326,7 +286,6 @@ class SweepEngine:
         self.robust = robust
         self.filter_size = int(filter_size)
         self.local_filter = int(local_filter)
-        self.backend = backend
         if isinstance(archive_capacity, str) and archive_capacity != "auto":
             raise ValueError("archive_capacity must be an int, None or "
                              f"'auto', got {archive_capacity!r}")
@@ -336,13 +295,7 @@ class SweepEngine:
         self.scenarios = scenarios
         self._portfolio = scenarios is not None
         if self._portfolio:
-            # deferred import (mirrors the ModelEvaluator import below):
-            # evaluator.py pulls this module back in lazily via the oracle
-            from repro.perfmodel.evaluator import homogeneous_models
             models = evaluator.models
-            if not homogeneous_models(models):
-                raise ValueError("portfolio sweeps need homogeneous workload "
-                                 "models (one class + compass-knob set)")
             self._scen_names = tuple(s.name for s in scenarios)
             self._wl_order = tuple(nm for s in scenarios
                                    for nm in (s.prefill, s.decode))
@@ -402,8 +355,7 @@ class SweepEngine:
 
         self._sharding = None
         ndev = len(jax.devices())
-        # the chunk must divide by the device count when sharding AND by the
-        # ppa_eval kernel's 256-row block on the pallas backend; ids past
+        # the chunk must divide by the device count when sharding; ids past
         # `stop` are masked invalid, so padding the chunk is always safe
         multiple = 1
         if shard and ndev > 1:
@@ -415,8 +367,6 @@ class SweepEngine:
                                  axis_types=(jax.sharding.AxisType.Auto,))
             self._sharding = NamedSharding(mesh, P("sweep"))
             multiple = ndev
-        if backend == "pallas":
-            multiple = math.lcm(multiple, 256)
         chunk_size = int(chunk_size)
         chunk_size += (-chunk_size) % multiple
         self.chunk_size = int(chunk_size)
@@ -457,13 +407,13 @@ class SweepEngine:
     def _autotune_chunk(self, candidates: Tuple[int, ...],
                         shard: bool) -> int:
         """Timed probe: one warmed chunk step per candidate size, keep the
-        highest-throughput one (memoized per process, like backend="auto").
+        highest-throughput one (memoized per process).
         Probe engines inherit the parent's shard flag so a sharded sweep is
         tuned on the sharded execution path."""
         if not candidates:
             raise ValueError("chunk_size='auto' needs a non-empty "
                              "chunk_candidates tuple")
-        key = (jax.default_backend(), self.backend, self.fingerprint(),
+        key = (jax.default_backend(), self.fingerprint(),
                int(self.stall_topk), bool(shard),
                tuple(int(c) for c in candidates))
         cached = _CHUNK_AUTO_CACHE.get(key)
@@ -477,7 +427,7 @@ class SweepEngine:
                 archive_capacity=self.archive_capacity,
                 ref_point=(self.ref_points if self._portfolio
                            else self.ref_point),
-                backend=self.backend, shard=shard, robust=self.robust,
+                shard=shard, robust=self.robust,
                 stall_topk=self.stall_topk, stall_rank=self.stall_rank)
             span = min(eng.chunk_size, self.size)
             eng.run(0, span)                       # compile + warm
@@ -498,28 +448,6 @@ class SweepEngine:
         """(c, n_params) int32 -> ((c, 3) objectives, dominant-stall (c,)
         or None), traced.  Decode + hardware derivation run once per chunk;
         stall attribution is only computed when stall_topk is enabled."""
-        if self.backend == "pallas":
-            from repro.kernels.ppa_eval.kernel import ppa_eval_fwd
-            from repro.kernels.ppa_eval.ref import op_table
-            with jax.named_scope("sweep.decode"):
-                vals = self.space.decode(idx)
-                dv = jnp.stack([vals[n] for n in self.space.names],
-                               axis=1).astype(jnp.float32)
-            interpret = jax.default_backend() != "tpu"
-            block_b = min(256, dv.shape[0])
-            with jax.named_scope("sweep.op_terms"):
-                o1 = ppa_eval_fwd(dv, jnp.asarray(op_table(self.ttft_model.wl),
-                                                  jnp.float32),
-                                  tp=float(self.ttft_model.wl.tp),
-                                  block_b=block_b, interpret=interpret)
-                o2 = ppa_eval_fwd(dv, jnp.asarray(op_table(self.tpot_model.wl),
-                                                  jnp.float32),
-                                  tp=float(self.tpot_model.wl.tp),
-                                  block_b=block_b, interpret=interpret)
-                ys = jnp.stack([o1[:, 0], o2[:, 0], o1[:, 5]], axis=1)
-                dom = (jnp.argmax(o1[:, 1:5], axis=1).astype(jnp.int32)
-                       if self.stall_topk else None)
-            return ys, dom
         with jax.named_scope("sweep.decode"):
             vals = self.space.decode(idx)
             hw = derive_hardware(vals)
@@ -819,7 +747,7 @@ class SweepEngine:
     def fingerprint(self) -> str:
         """Identity of (space, workloads, knobs) for checkpoint validation."""
         if self._portfolio:
-            parts = [str(self._cards), self.backend,
+            parts = [str(self._cards), "roofline",
                      f"robust={self.robust}",
                      type(self._rep_model).__qualname__]
             for s in self.scenarios:
@@ -833,7 +761,7 @@ class SweepEngine:
                 parts.append(f"stall_rank={self.stall_rank}")
             return "|".join(parts)
         parts = [
-            str(self._cards), self.backend,
+            str(self._cards), "roofline",
             _workload_fingerprint(self.ttft_model.wl),
             _workload_fingerprint(self.tpot_model.wl),
             type(self.ttft_model).__qualname__,
@@ -1318,8 +1246,8 @@ class SweepEngine:
                 f"build's v{_FMT_VERSION}; refusing to resume")
         if str(z["fingerprint"]) != self.fingerprint() + fp_extra:
             raise ValueError(
-                "checkpoint was produced by a different space/workload/"
-                "backend configuration (or a different worker span); "
+                "checkpoint was produced by a different space/workload "
+                "configuration (or a different worker span); "
                 "refusing to resume")
         if not np.allclose(np.asarray(z["ref_point"]), self.ref_point,
                            rtol=1e-6):

@@ -2,10 +2,9 @@
 
 Covers: the fused multi-workload dispatch is bit-identical to per-model
 single-workload dispatches on both fidelity tiers; the batched multi-design
-path is bit-identical to N single-design dispatches; the Pallas kernel
-backend agrees with the traced roofline backend; one DSE step costs exactly
-one fused dispatch; the pre-PR-2 deprecation shims are GONE; the oracle
-tier normalizes PHV against the exhaustive front; and the sweep's
+path is bit-identical to N single-design dispatches; one DSE step costs
+exactly one fused dispatch; the pre-PR-2 deprecation shims are GONE; the
+oracle tier normalizes PHV against the exhaustive front; and the sweep's
 per-stall-class top-k matches brute force.
 """
 import jax
@@ -20,7 +19,7 @@ from repro.perfmodel import (CompassModel, EvalRequest, ModelEvaluator,
                              gpt3_layer_prefill, gpt3_layer_decode)
 from repro.perfmodel.designspace import SPACE, A100_REFERENCE
 from repro.perfmodel.evaluator import (DETAILS, as_evaluator,
-                                       evaluator_for_model, resolve_backend)
+                                       evaluator_for_model)
 from repro.perfmodel.roofline import (PackedFn, _batch_bucket, _bucketed_call,
                                       _split_sinks)
 from repro.perfmodel.sweep import SweepEngine
@@ -115,13 +114,11 @@ def test_stall_report_matches_attribute_stalls(tier_setup):
 # ------------------------------------------------- one-copy packed fetch
 @pytest.fixture(scope="module")
 def zoo_evaluator():
-    """A stacked zoo-portfolio evaluator."""
+    """A zoo-portfolio evaluator."""
     from repro.perfmodel.workload import zoo_suite
     wls, scen = zoo_suite(archs=("qwen2-moe-a2.7b", "rwkv6-7b"), smoke=True,
                           batch=4)
-    ev = make_evaluator(wls, tier="proxy", scenarios=scen)
-    assert ev.stacked
-    return ev
+    return make_evaluator(wls, tier="proxy", scenarios=scen)
 
 
 def _per_leaf_report(fn, idx):
@@ -197,52 +194,13 @@ def test_packed_fn_rejects_unpackable_leaves():
         _bucketed_call(flat, idx)
 
 
-# ------------------------------------------------------- backend registry
-def test_pallas_backend_parity(sample_idx):
-    """Kernel-backend objectives agree with the traced roofline backend on a
-    sampled id set (interpret-mode tolerance, cf. tests/test_kernels.py)."""
-    base = get_evaluator("proxy")
-    pal = ModelEvaluator(base.models, backend="pallas")
-    y_ref = base.objectives(sample_idx)
-    y_pal = pal.objectives(sample_idx)
-    np.testing.assert_allclose(y_pal[:, :2], y_ref[:, :2], rtol=1e-4)
-    np.testing.assert_allclose(y_pal[:, 2], y_ref[:, 2], rtol=1e-5)
-
-
-def test_pallas_rejects_compass_models():
-    ct = CompassModel(gpt3_layer_prefill())
-    cp = CompassModel(gpt3_layer_decode())
-    with pytest.raises(ValueError, match="roofline tier"):
-        ModelEvaluator({"ttft": ct, "tpot": cp}, backend="pallas")
-
-
+# ------------------------------------------------------- oracle base tier
 def test_unknown_backend_rejected():
-    with pytest.raises(ValueError, match="unknown backend"):
-        ModelEvaluator(get_evaluator("proxy").models, backend="gem5")
-
-
-def test_auto_backend_resolves_to_registered_name():
-    models = get_evaluator("proxy").models
-    name = resolve_backend("auto", models)
-    assert name in ("roofline", "pallas")
-    # compass-tier knobs are never routed to the kernel
-    ct = {"ttft": CompassModel(gpt3_layer_prefill()),
-          "tpot": CompassModel(gpt3_layer_decode())}
-    assert resolve_backend("auto", ct) == "roofline"
-
-
-def test_auto_backend_raises_when_a_candidate_fails(monkeypatch):
-    """backend="auto" never hides a broken candidate behind another pick."""
-    import repro.perfmodel.evaluator as E
-    monkeypatch.setattr(E, "_AUTO_CACHE", {})
-    monkeypatch.setattr(E, "_JIT_CACHE", {})
-
-    def broken(self, names):
-        raise NotImplementedError("kernel failed to lower")
-
-    monkeypatch.setattr(E.ModelEvaluator, "_build_kernel_objectives", broken)
-    with pytest.raises(NotImplementedError, match="failed to lower"):
-        resolve_backend("auto", get_evaluator("proxy").models)
+    """The oracle sweeps the proxy or the target tier, and nothing else."""
+    with pytest.raises(ValueError, match="oracle_base"):
+        get_evaluator("oracle", oracle_base="compass")
+    base = get_evaluator("oracle", oracle_base="target", oracle_stop=8_192)
+    assert base.base is get_evaluator("target")
 
 
 # ------------------------------------------------------- dispatch counting
@@ -273,6 +231,8 @@ def test_legacy_shims_removed():
         assert not hasattr(mt, attr), attr
     with pytest.raises(TypeError):
         as_evaluator(mt, mp)
+    with pytest.raises(TypeError):
+        SweepEngine(mt, mp, chunk_size=8_192)
     with pytest.raises(TypeError):
         from repro.core.loop import LuminaDSE
         LuminaDSE(mt, mp)
@@ -363,12 +323,3 @@ def test_sweep_stall_topk_checkpoint_roundtrip(tmp_path):
     strict = SweepEngine(ev, chunk_size=8_192, stall_topk=4)
     with pytest.raises(ValueError, match="stall"):
         strict.run(0, SUB, resume_from=ck + "2")
-
-
-def test_sweep_engine_from_evaluator_matches_pair():
-    mt, mp = (get_evaluator("proxy").models[w] for w in ("ttft", "tpot"))
-    a = SweepEngine(get_evaluator("proxy"), chunk_size=8_192)
-    b = SweepEngine(mt, mp, chunk_size=8_192)
-    ra, rb = a.run(0, SUB // 2), b.run(0, SUB // 2)
-    assert ra.n_superior == rb.n_superior
-    assert np.array_equal(ra.pareto_ids, rb.pareto_ids)

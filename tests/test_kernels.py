@@ -10,10 +10,6 @@ from repro.kernels.rwkv6_scan.ops import rwkv6_scan
 from repro.kernels.rwkv6_scan.ref import rwkv6_scan_ref
 from repro.kernels.ssm_scan.ops import ssm_scan
 from repro.kernels.ssm_scan.ref import ssm_scan_ref
-from repro.kernels.ppa_eval.ops import ppa_eval
-from repro.kernels.ppa_eval.ref import ppa_eval_ref
-from repro.perfmodel.designspace import SPACE
-from repro.perfmodel.workload import gpt3_layer_prefill, gpt3_layer_decode
 
 RNG = np.random.default_rng(0)
 
@@ -86,18 +82,6 @@ def test_ssm_scan(b, t, d, n, bt, bd, dtype):
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("wl_fn", [gpt3_layer_prefill, gpt3_layer_decode])
-@pytest.mark.parametrize("n", [64, 300])
-def test_ppa_eval(wl_fn, n):
-    wl = wl_fn()
-    idx = SPACE.sample(np.random.default_rng(7), n)
-    out = ppa_eval(idx, wl, interpret=True)
-    ref = ppa_eval_ref(idx, wl)
-    np.testing.assert_allclose(out["latency"], ref[:, 0], rtol=1e-4)
-    np.testing.assert_allclose(out["area"], ref[:, 5], rtol=1e-5)
-    np.testing.assert_allclose(out["stall"], ref[:, 1:5], rtol=1e-4, atol=1e-9)
 
 
 def test_model_uses_chunked_for_long_seq():

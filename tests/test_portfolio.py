@@ -1,11 +1,10 @@
 """Stacked-workload evaluation + portfolio sweep (PR 5 invariants).
 
-Covers: stacked vs looped bit-identity at every detail level across zoo
-workloads (incl. a MoE and an SSM config) on both fidelity tiers; the
-WorkloadStack dedup / count-matrix / gather-map correctness vs brute-force
-concatenation; ONE compiled executable per (detail, suite) regardless of
-the workload count; the zoo-suite evaluator wiring
-(``get_evaluator(suite="zoo")``); the portfolio sweep's per-scenario
+Covers: the evaluator's refusal of a model set its one op-term pass
+cannot serve; the WorkloadStack dedup / count-matrix / gather-map
+correctness vs brute-force concatenation; ONE compiled executable per
+(detail, suite) regardless of the workload count; the zoo-suite evaluator
+wiring (``get_evaluator(suite="zoo")``); the portfolio sweep's per-scenario
 fronts / top-k / stall seeds and the robust front vs brute force, its
 worker sharding and checkpoint resume; archive auto-capacity; and
 scenario-class seeded campaigns through ``CampaignRunner``.
@@ -41,41 +40,17 @@ def zoo_ev(suite):
     return make_evaluator(wls, tier="proxy", scenarios=scen)
 
 
-# --------------------------------------------------- stacked == looped
-@pytest.mark.parametrize("cls", [RooflineModel, CompassModel],
-                         ids=["proxy", "target"])
-@pytest.mark.parametrize("detail", ["objectives", "ppa", "stalls"])
-def test_stacked_bit_identical_to_looped(suite, cls, detail):
-    """The stacked union pass reproduces the per-workload looped dispatch
-    EXACTLY — every field, every detail level, both tiers, across MoE/SSM/
-    dense zoo workloads."""
-    wls, _ = suite
-    models = {nm: cls(wl) for nm, wl in wls.items()}
-    stacked = ModelEvaluator(models, stacked=True)
-    looped = ModelEvaluator(models, stacked=False)
-    idx = SPACE.sample(np.random.default_rng(1), 16)
-    a = stacked.evaluate(EvalRequest(idx, detail=detail))
-    b = looped.evaluate(EvalRequest(idx, detail=detail))
-    assert np.array_equal(a.area, b.area)
-    for w in stacked.workloads:
-        assert np.array_equal(a.latency[w], b.latency[w]), w
-        if detail in ("ppa", "stalls"):
-            assert np.array_equal(a.op_time[w], b.op_time[w]), w
-        if detail == "stalls":
-            assert np.array_equal(a.stall[w], b.stall[w]), w
-            assert np.array_equal(a.op_class[w], b.op_class[w]), w
-
-
+# --------------------------------------------------- one op-term pass
 def test_stacked_rejects_heterogeneous_models(suite):
+    """The union pass computes every workload with one model's op-term
+    math, so a mixed-class set is refused, not silently looped."""
     wls, _ = suite
     names = list(wls)
     models = {names[0]: RooflineModel(wls[names[0]]),
               names[1]: CompassModel(wls[names[1]])}
-    with pytest.raises(ValueError, match="stacked"):
-        ModelEvaluator(models, stacked=True)
-    # auto mode silently falls back to the looped path
-    ev = ModelEvaluator(models)
-    assert ev.stacked is False
+    with pytest.raises(ValueError, match="one class"):
+        ModelEvaluator(models)
+    ModelEvaluator({nm: models[nm] for nm in names[:1]})    # one class: fine
 
 
 # --------------------------------------------------- WorkloadStack dedup
@@ -128,7 +103,6 @@ def test_get_evaluator_zoo_suite():
     ev = get_evaluator("proxy", suite="zoo")
     assert ev is get_evaluator("proxy", suite="zoo")       # memoized
     assert ev is not get_evaluator("proxy")                # distinct key
-    assert ev.stacked
     assert len(ev.scenarios) == 11                         # every arch config
     names = {s.name for s in ev.scenarios}
     assert {"arctic-480b", "rwkv6-7b", "whisper-medium"} <= names
@@ -222,8 +196,6 @@ def test_portfolio_geomean_and_validation(zoo_ev):
         SweepEngine(zoo_ev, robust="median")
     with pytest.raises(KeyError, match="scenario"):
         resg.stall_seeds(scenario="gpt5")
-    with pytest.raises(ValueError, match="roofline"):
-        SweepEngine(zoo_ev, backend="pallas")
 
 
 def test_portfolio_stall_seeds_flatten(zoo_ev, swept):

@@ -263,11 +263,6 @@ def test_chunk_step_names_its_phases_in_hlo_metadata(engine,
                          text), scope
 
 
-def test_pallas_backend_rejects_compass_models():
-    with pytest.raises(ValueError, match="pallas"):
-        SweepEngine(get_evaluator("target"), backend="pallas")
-
-
 # ----------------------------------------------------- run_method plumbing
 def test_run_method_incremental_phv_curve():
     from repro.core.baselines import METHODS, run_method
